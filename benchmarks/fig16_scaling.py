@@ -9,10 +9,14 @@ extends the curve to 256/512 chips (EXPERIMENTS.md §Dry-run).
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 from .common import row
+
+ROOT = Path(__file__).resolve().parents[1]
 
 _SCRIPT = r'''
 import os
@@ -46,15 +50,16 @@ print("RESULTS=" + json.dumps(results))
 
 
 def run():
+    # a virtual-device CPU rehearsal: the parent has imported JAX and may
+    # hold the accelerator, so the child must never ask for it
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"), JAX_PLATFORMS="cpu")
     res = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=3600, cwd="/root/repo",
-        env={"PYTHONPATH": "src", "HOME": "/root", "PATH": "/usr/bin:/bin"})
+        timeout=3600, cwd=ROOT, env=env)
     line = [l for l in res.stdout.splitlines() if l.startswith("RESULTS=")]
-    if not line:
-        print("fig16_scaling,0,FAILED:" + res.stderr[-200:].replace(
-            "\n", " "))
-        return
+    if res.returncode != 0 or not line:
+        raise RuntimeError(f"fig16_scaling child failed (rc "
+                           f"{res.returncode}): {res.stderr[-2000:]}")
     results = {int(k): v for k, v in json.loads(line[0][8:]).items()}
     t1 = results[1]
     # Amdahl fit: t(p) = t1*(s + (1-s)/p) — least squares over measured p

@@ -31,6 +31,8 @@ def main() -> None:
                     help="skip the multi-process scaling benchmark")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.configure()
     from . import (bench_column_solve, bench_horizontal_rhs, fig13_resolution,
                    fig15_layers, fig16_scaling, kernel_util, roofline_table)
     benches = {

@@ -1,5 +1,4 @@
 import jax
-import pytest
 
 # Deterministic dtype policy for the whole suite: several test modules need
 # f64 (solver exactness); module import order at collection must not change
@@ -10,60 +9,3 @@ jax.config.update("jax_enable_x64", True)
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running test (subprocess)")
 
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback: the tier-1 suite must collect and pass on a bare
-# jax+pytest environment.  When hypothesis is unavailable, install a tiny
-# deterministic shim that expands @given(sampled_from/booleans) into a
-# pytest.mark.parametrize over the full Cartesian product — every example the
-# real hypothesis would draw from these finite strategies, minus shrinking.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import itertools
-    import sys
-    import types
-
-    class _Strategy:
-        def __init__(self, values):
-            self.values = list(values)
-
-    def _sampled_from(values):
-        return _Strategy(values)
-
-    def _booleans():
-        return _Strategy([False, True])
-
-    def _integers(min_value=0, max_value=8):
-        return _Strategy(range(min_value, max_value + 1))
-
-    def _given(**strategies):
-        names = sorted(strategies)
-        combos = list(itertools.product(
-            *(strategies[n].values for n in names)))
-
-        def deco(fn):
-            if len(names) == 1:
-                values = [c[0] for c in combos]
-            else:
-                values = combos
-            return pytest.mark.parametrize(",".join(names), values)(fn)
-        return deco
-
-    def _settings(*args, **kwargs):
-        def deco(fn):
-            return fn
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.sampled_from = _sampled_from
-    _st.booleans = _booleans
-    _st.integers = _integers
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_repro_shim__ = True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

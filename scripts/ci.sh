@@ -1,7 +1,6 @@
 #!/usr/bin/env bash
-# Tier-1 CI gate: the suite must COLLECT (zero import errors — catching
-# missing-optional-dependency regressions like the hypothesis one) and PASS
-# on a bare jax+pytest environment, within a time budget.
+# Tier-1 CI gate: the suite must COLLECT (zero import errors) and PASS
+# within a time budget.
 #
 # Usage: scripts/ci.sh [--obs-smoke|--chaos-smoke] [extra pytest args]
 #   --obs-smoke   run ONLY the observability smoke: a 3-step instrumented

@@ -57,6 +57,11 @@ class OceanConfig:
                                    # k-stacked momentum/tracer advdiff
                                    # (core/horizontal.py); False keeps the
                                    # seed per-call path (equivalence oracle)
+    matmul_precision: str = "highest"  # of every f32 contraction in the
+                                       # step; TPU's "default" takes bf16
+                                       # passes, under which the external
+                                       # mode blows up within a few steps
+                                       # (CPU computes f32 in full either way)
 
     def with_recovery(self, dt_factor: float = 0.5,
                       visc_factor: float = 1.0) -> "OceanConfig":
@@ -410,17 +415,18 @@ def step(geom: G.Geom2D, vg: VGrid, cfg: OceanConfig, st: OceanState,
     supplied by the distributed runtime (distributed/ocean.py)."""
     turb0 = turbulence.TurbState(st.turb_k, st.turb_eps, st.nu_t, st.kappa_t)
 
-    with jax.named_scope("imex.stage1"):
-        s1 = stage(geom, vg, cfg, st, st.ux, st.uy, st.T, st.S, st.ext.eta,
-                   turb0, cfg.dt / 2, max(cfg.m_2d // 2, 1),
-                   cfg.implicit_stage1, forcing,
-                   exchange2d=exchange2d, exchange_field=exchange_field)
+    with jax.default_matmul_precision(cfg.matmul_precision):
+        with jax.named_scope("imex.stage1"):
+            s1 = stage(geom, vg, cfg, st, st.ux, st.uy, st.T, st.S,
+                       st.ext.eta, turb0, cfg.dt / 2, max(cfg.m_2d // 2, 1),
+                       cfg.implicit_stage1, forcing,
+                       exchange2d=exchange2d, exchange_field=exchange_field)
 
-    with jax.named_scope("imex.stage2"):
-        s2 = stage(geom, vg, cfg, st, s1.ux, s1.uy, s1.T, s1.S, s1.ext.eta,
-                   s1.turb, cfg.dt, cfg.m_2d, False, forcing,
-                   turb_base=turb0,
-                   exchange2d=exchange2d, exchange_field=exchange_field)
+        with jax.named_scope("imex.stage2"):
+            s2 = stage(geom, vg, cfg, st, s1.ux, s1.uy, s1.T, s1.S,
+                       s1.ext.eta, s1.turb, cfg.dt, cfg.m_2d, False, forcing,
+                       turb_base=turb0,
+                       exchange2d=exchange2d, exchange_field=exchange_field)
 
     return OceanState(
         ext=s2.ext, ux=s2.ux, uy=s2.uy, T=s2.T, S=s2.S,

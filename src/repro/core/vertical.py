@@ -290,34 +290,39 @@ def block_thomas_solve(blocks: Blocks, rhs: jax.Array) -> jax.Array:
     """
     lo, dg, up = blocks
     k, nl, _, nt = rhs.shape
-    # reshape to (nl, nt, 6, 6) / (nl, nt, 6, k) for batched linalg
-    loT = jnp.moveaxis(lo, -1, 1)
-    dgT = jnp.moveaxis(dg, -1, 1)
-    upT = jnp.moveaxis(up, -1, 1)
-    bT = jnp.moveaxis(jnp.moveaxis(rhs, 0, -1), -2, 1)   # (nl, nt, 6, k)
+    # Arrays stacked over the layers keep the column axis last; only one
+    # layer at a time is turned to (nt, 6, 6) for the batched linalg.  (A
+    # TPU pads a trailing (6, 6) pair to an (8, 128) tile: stacked, that
+    # was 28 times the data, about 7 GB at 52k columns and 32 layers.)
+    def cols(a):                          # (r, c, nt) -> (nt, r, c)
+        return jnp.moveaxis(a.reshape(6, -1, nt), -1, 0)
+
+    def lanes(a):                         # (nt, r, c) -> (r * c, nt)
+        return jnp.moveaxis(a, 0, -1).reshape(-1, nt)
 
     def fwd(carry, inp):
         C_prev, y_prev = carry                           # (nt,6,6), (nt,6,k)
-        L, D, U, b = inp
+        L, D, U, b = map(cols, inp)
         S = D - L @ C_prev
         Cy = jnp.linalg.solve(S, jnp.concatenate([U, b - L @ y_prev], axis=-1))
         C = Cy[..., :6]
         y = Cy[..., 6:]
-        return (C, y), (C, y)
+        return (C, y), (lanes(C), lanes(y))
 
     C0 = jnp.zeros((nt, 6, 6), rhs.dtype)
     y0 = jnp.zeros((nt, 6, k), rhs.dtype)
-    _, (Cs, ys) = jax.lax.scan(fwd, (C0, y0), (loT, dgT, upT, bT))
+    _, (Cs, ys) = jax.lax.scan(fwd, (C0, y0),
+                               tuple(a.reshape(nl, -1, nt) for a in
+                                     (lo, dg, up, jnp.moveaxis(rhs, 0, 2))))
 
     def bwd(x_next, inp):
-        C, y = inp
+        C, y = map(cols, inp)
         x = y - C @ x_next
-        return x, x
+        return x, lanes(x)
 
     _, xs = jax.lax.scan(bwd, jnp.zeros((nt, 6, k), rhs.dtype), (Cs, ys),
                          reverse=True)
-    # (nl, nt, 6, k) -> (k, nl, 6, nt)
-    return jnp.moveaxis(jnp.moveaxis(xs, 1, -1), -2, 0)
+    return jnp.moveaxis(xs.reshape(nl, 6, k, nt), 2, 0)    # (k, nl, 6, nt)
 
 
 def blocks_dense(blocks: Blocks) -> jax.Array:

@@ -19,6 +19,7 @@ from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import partition as part
 from ..obs import metrics as _metrics
@@ -38,11 +39,11 @@ class HaloTables:
 
 def tables_from_spec(spec: part.PartitionSpec2D,
                      axes: Sequence[str]) -> HaloTables:
-    """Stacked (P, S) numpy tables -> HaloTables pytree (stacked; shard_map
-    shards the leading axis)."""
+    """Stacked (P, S) numpy tables -> HaloTables pytree of host arrays
+    (stacked; the caller places them, shard_map shards the leading axis)."""
     offs = tuple(sorted(spec.tables.keys()))
-    send = tuple(jnp.asarray(spec.tables[o][0], jnp.int32) for o in offs)
-    recv = tuple(jnp.asarray(spec.tables[o][1], jnp.int32) for o in offs)
+    send = tuple(np.asarray(spec.tables[o][0], np.int32) for o in offs)
+    recv = tuple(np.asarray(spec.tables[o][1], np.int32) for o in offs)
     return HaloTables(send=send, recv=recv, offsets=offs,
                       n_devices=spec.n_parts, axes=tuple(axes))
 

@@ -9,7 +9,6 @@ or a 512-chip double pod.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
 from typing import Optional, Sequence
 
@@ -32,15 +31,12 @@ class DistributedOcean:
                  cfg: stepper.OceanConfig, device_mesh: jax.sharding.Mesh,
                  axes: Sequence[str], halo_depth: Optional[int] = None,
                  dtype=jnp.float32):
-        # the shard_map'd local step runs on halo-extended partitions whose
-        # local nt varies per rank; pin the column solves to the jnp
-        # reference there (the Pallas path is exercised — and equivalence-
-        # tested — on the single-device stepper, kernels/dispatch.py)
+        # the shard-local solves run the jnp reference until the kernels are
+        # wired under shard_map; "auto" means "ref" here
         if cfg.backend not in ("auto", "ref"):
-            warnings.warn(
-                f"DistributedOcean: backend={cfg.backend!r} is not supported "
-                "in the shard_map'd local step; falling back to 'ref' for "
-                "the distributed column solves.", stacklevel=2)
+            raise ValueError(
+                f"DistributedOcean runs backend 'ref' only, got "
+                f"{cfg.backend!r}; pass backend='auto' or 'ref'")
         cfg = dataclasses.replace(cfg, backend="ref")
         self.cfg = cfg
         self.device_mesh = device_mesh
@@ -50,15 +46,22 @@ class DistributedOcean:
             halo_depth = max(1, 3 * cfg.halo_exchange_period)
         self.spec = partition.build_partition(mesh, n_parts, halo_depth)
         self.n_parts = n_parts
+        self.pspec = PartitionSpec(self.axes)
+        # every per-partition array lives on its own device: the leading
+        # partition axis is sharded over the mesh, and nothing is placed
+        # whole on one device first
+        self.sharding = NamedSharding(device_mesh, self.pspec)
 
         lms = partition.local_meshes(mesh, self.spec)
-        geoms = [geometry.geom2d_from_mesh(lm, dtype=dtype) for lm in lms]
-        self.geom_stk = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *geoms)
-        self.b_stk = jnp.asarray(
-            partition.scatter_field(self.spec, np.asarray(b_nodal)), dtype)
-        self.tables = halo.tables_from_spec(self.spec, self.axes)
-        self.pspec = PartitionSpec(self.axes)
+        geoms = [jax.tree_util.tree_map(
+            np.asarray, geometry.geom2d_from_mesh(lm, dtype=dtype))
+            for lm in lms]
+        self.geom_stk = self._place(jax.tree_util.tree_map(
+            lambda *xs: np.stack(xs), *geoms))
+        self.b_stk = self._place(partition.scatter_field(
+            self.spec, np.asarray(b_nodal, dtype)))
+        self.tables = self._place(halo.tables_from_spec(self.spec,
+                                                        self.axes))
 
         # static partition facts -> metrics (per-rank halo sizing context
         # for the traced halo.bytes / halo.ppermute counters)
@@ -69,15 +72,19 @@ class DistributedOcean:
         reg.gauge("distributed.halo_slots").set(
             sum(int(s.shape[-1]) for s in self.tables.send))
 
+    def _place(self, tree):
+        """Host arrays with a leading partition axis -> sharded arrays."""
+        return jax.device_put(tree, self.sharding)
+
     # -- state scatter/gather -------------------------------------------------
     def scatter_state(self, st: stepper.OceanState) -> stepper.OceanState:
         spec = self.spec
         def sc(x):
             x = np.asarray(x)
             if x.ndim == 0:
-                return jnp.broadcast_to(jnp.asarray(x), (spec.n_parts,))
-            return jnp.asarray(partition.scatter_field(spec, x))
-        return jax.tree_util.tree_map(sc, st)
+                return np.broadcast_to(x, (spec.n_parts,))
+            return partition.scatter_field(spec, x)
+        return self._place(jax.tree_util.tree_map(sc, st))
 
     def gather_state(self, st_stk: stepper.OceanState) -> stepper.OceanState:
         spec = self.spec
@@ -90,52 +97,18 @@ class DistributedOcean:
 
     def init_state(self) -> stepper.OceanState:
         """Stacked initial state (already partitioned)."""
-        nt_loc = self.spec.n_loc
-        # build on a dummy geom of local size
         geom0 = jax.tree_util.tree_map(lambda x: x[0], self.geom_stk)
         vg = VGrid(b=self.b_stk[0], nl=self.cfg.nl)
         st = stepper.init_state(geom0, vg)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (self.n_parts,) + x.shape),
-            st)
+        return self._place(jax.tree_util.tree_map(
+            lambda x: np.broadcast_to(np.asarray(x)[None],
+                                      (self.n_parts,) + x.shape), st))
 
     # -- the sharded step -------------------------------------------------------
-    def make_step(self, forcing: Optional[stepper.Forcing3D] = None):
-        cfg = self.cfg
-        forcing = forcing if forcing is not None else stepper.Forcing3D()
-
-        def local_step(geom_s, b_s, tables_s, state_s):
-            geom = halo.squeeze_local(geom_s)
-            b = b_s[0]
-            tables = halo.squeeze_local(tables_s)
-            st = halo.squeeze_local(state_s)
-            vg = VGrid(b=b, nl=cfg.nl)
-
-            def ex2d(s2):
-                eta, qx, qy = halo.exchange_batch(
-                    [s2.eta, s2.qx, s2.qy], tables)
-                return State2D(eta, qx, qy)
-
-            exf = lambda f: halo.exchange(f, tables)
-            with jax.named_scope("distributed.local_step"):
-                st1 = stepper.step(geom, vg, cfg, st, forcing,
-                                   exchange2d=ex2d, exchange_field=exf)
-            return jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], st1)
-
-        shmap = jax.shard_map(
-            local_step, mesh=self.device_mesh,
-            in_specs=(self.pspec, self.pspec, self.pspec, self.pspec),
-            out_specs=self.pspec, check_vma=False)
-
-        def step_fn(state_stk):
-            return shmap(self.geom_stk, self.b_stk, self.tables, state_stk)
-
-        return jax.jit(step_fn)
-
     def make_step_args(self, forcing: Optional[stepper.Forcing3D] = None):
-        """Un-closed variant for the dry-run: the shard-mapped step as a
-        function of (geom, b, tables, state) so it can be lowered with
-        ShapeDtypeStruct arguments (no allocation at GBR scale)."""
+        """The shard-mapped step as a function of (geom, b, tables, state),
+        all stacked over the partition axis; ``abstract_args`` gives their
+        shapes for an ahead-of-time lowering."""
         cfg = self.cfg
         forcing = forcing if forcing is not None else stepper.Forcing3D()
 
@@ -161,6 +134,12 @@ class DistributedOcean:
             local_step, mesh=self.device_mesh,
             in_specs=(self.pspec, self.pspec, self.pspec, self.pspec),
             out_specs=self.pspec, check_vma=False)
+
+    def make_step(self, forcing: Optional[stepper.Forcing3D] = None):
+        """Jitted ``state_stk -> state_stk``.  The placed partition data go
+        in as arguments, not as constants of the program."""
+        fn = jax.jit(self.make_step_args(forcing))
+        return partial(fn, self.geom_stk, self.b_stk, self.tables)
 
     def abstract_args(self):
         """ShapeDtypeStruct stand-ins for (geom, b, tables, state)."""

@@ -34,27 +34,43 @@ from . import dispatch
 
 
 def _mm6(A, B):
-    """(6,6,BC) @ (6,m,BC) per-lane matmul, unrolled."""
-    return jnp.einsum("ikc,kmc->imc", A, B)
+    """(6,6,BC) @ (6,m,BC) per-lane matmul, unrolled into f32 multiply-adds
+    on the vector unit.  (An einsum here lowers to a matmul whose f32
+    precision follows the caller's default: on a TPU, bf16 passes that put
+    the solve 7e-4 off its oracle.)"""
+    out = A[:, 0:1] * B[0:1]
+    for k in range(1, 6):
+        out = out + A[:, k:k + 1] * B[k:k + 1]
+    return out
 
 
-def _solve6(S, rhs):
-    """Per-lane solve of S x = rhs via unrolled Gaussian elimination.
+def _solve6(S, *rhs):
+    """Per-lane solve of S x = r for each r in rhs via unrolled Gauss-Jordan
+    elimination.
 
-    S: (6, 6, BC); rhs: (6, m, BC).  No pivoting (diagonally dominant)."""
+    S: (6, 6, BC); each r: (6, m, BC); returns one (6, m, BC) solution per
+    r.  No pivoting (diagonally dominant).  The entries of S are held as 36
+    separate (BC,) lane-vectors and the rows of each r as six (m, BC)
+    values, stacked once at the end: Mosaic lowers neither a scatter (an
+    `.at[]` update) nor a sublane-axis concatenate of the right-hand sides.
+    Columns left of the pivot are already zero in the pivot row, so only
+    columns right of it are updated (their updates would add exact zeros)."""
+    s = [[S[r, c] for c in range(6)] for r in range(6)]
+    xs = [[x[r] for r in range(6)] for x in rhs]
     for col in range(6):
-        inv = 1.0 / S[col, col]
-        Srow = S[col] * inv                   # (6, BC)
-        rrow = rhs[col] * inv                 # (m, BC)
-        S = S.at[col].set(Srow)
-        rhs = rhs.at[col].set(rrow)
+        inv = 1.0 / s[col][col]
+        s[col] = [s[col][j] * inv for j in range(6)]
+        for x in xs:
+            x[col] = x[col] * inv
         for r in range(6):
             if r == col:
                 continue
-            f = S[r, col]
-            S = S.at[r].add(-f * Srow)
-            rhs = rhs.at[r].add(-f * rrow)
-    return rhs
+            f = s[r][col]
+            s[r] = [s[r][j] - f * s[col][j] if j > col else s[r][j]
+                    for j in range(6)]
+            for x in xs:
+                x[r] = x[r] - f * x[col]
+    return tuple(jnp.stack(x) for x in xs)
 
 
 def _block_thomas_kernel(lo_ref, dg_ref, up_ref, b_ref, x_ref, C_ref):
@@ -65,10 +81,7 @@ def _block_thomas_kernel(lo_ref, dg_ref, up_ref, b_ref, x_ref, C_ref):
         C_prev, y_prev = carry               # (6,6,BC), (6,k,BC)
         L = lo_ref[l]
         S = dg_ref[l] - _mm6(L, C_prev)
-        rhs = jnp.concatenate([up_ref[l], b_ref[l] - _mm6(L, y_prev)], axis=1)
-        sol = _solve6(S, rhs)                # (6, 6+k, BC)
-        C = sol[:, :6, :]
-        y = sol[:, 6:, :]
+        C, y = _solve6(S, up_ref[l], b_ref[l] - _mm6(L, y_prev))
         C_ref[l] = C
         x_ref[l] = y                         # stash y; fixed in backward sweep
         return C, y

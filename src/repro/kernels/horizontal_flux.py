@@ -64,8 +64,10 @@ def _lateral_flux_kernel(f_ref, fe_ref, sp_ref, wq_ref, out_ref):
         fb = f_ref[pl.dslice(base + 3, 3), :]          # bottom-face nodal
         ext = fe_ref[pl.dslice(l * 12, 12), :]         # (12, BC)
         spd = sp_ref[pl.dslice(l * 12, 12), :]         # (12, BC)
-        acc_t = jnp.zeros_like(ft)
-        acc_b = jnp.zeros_like(fb)
+        # three lane-vectors per face, stacked once before the store (Mosaic
+        # has no scatter, so no `.at[]` update may appear here)
+        acc_t = [jnp.zeros_like(ft[0]) for _ in range(3)]
+        acc_b = [jnp.zeros_like(fb[0]) for _ in range(3)]
         for e in range(3):
             na, nb = _EDGE_A[e], _EDGE_B[e]
             for z in range(2):
@@ -83,10 +85,12 @@ def _lateral_flux_kernel(f_ref, fe_ref, sp_ref, wq_ref, out_ref):
                     g = jnp.where(sp > 0, fi, fe) * sp * wq[e * 2 + q]
                     ca = _PHIA[q] * g                  # node-a test function
                     cb = _PHIB[q] * g
-                    acc_t = acc_t.at[na].add(pzt * ca).at[nb].add(pzt * cb)
-                    acc_b = acc_b.at[na].add(pzb * ca).at[nb].add(pzb * cb)
-        out_ref[pl.dslice(base, 3), :] = acc_t
-        out_ref[pl.dslice(base + 3, 3), :] = acc_b
+                    acc_t[na] = acc_t[na] + pzt * ca
+                    acc_t[nb] = acc_t[nb] + pzt * cb
+                    acc_b[na] = acc_b[na] + pzb * ca
+                    acc_b[nb] = acc_b[nb] + pzb * cb
+        out_ref[pl.dslice(base, 3), :] = jnp.stack(acc_t)
+        out_ref[pl.dslice(base + 3, 3), :] = jnp.stack(acc_b)
         return carry
 
     jax.lax.fori_loop(0, nl, body, 0)

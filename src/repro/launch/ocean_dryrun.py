@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core import geometry, mesh2d, stepper
@@ -49,7 +50,22 @@ OCEAN_CELLS = {
 }
 
 
-def build_cell(cell: OceanCell, device_mesh):
+def chip_share(cell: OceanCell, n_chips: int = 4) -> OceanCell:
+    """One chip's share of ``cell`` spread over ``n_chips`` (a square
+    number): the domain is cut along both axes at the same element size,
+    so dt, m_2d and the CFL numbers stay those of the full cell."""
+    k = int(round(n_chips ** 0.5))
+    if k * k != n_chips or cell.nx % k or cell.ny % k:
+        raise ValueError(f"{cell.name}: cannot cut into {n_chips} squares")
+    return dataclasses.replace(cell, name=f"{cell.name}/{n_chips}",
+                               nx=cell.nx // k, ny=cell.ny // k,
+                               lx=cell.lx / k, ly=cell.ly / k)
+
+
+def cell_setup(cell: OceanCell, dtype=jnp.float32):
+    """Mesh, geometry, nodal bathymetry (3, nt) and OceanConfig of a cell:
+    the one description the dry-run, the campaign and ``chip_smoke.py``
+    share."""
     m = mesh2d.rect_mesh(cell.nx, cell.ny, cell.lx, cell.ly, jitter=0.2,
                          seed=7)
     if cell.reef:
@@ -57,17 +73,21 @@ def build_cell(cell: OceanCell, device_mesh):
                                     cell.ly)
     else:
         bf = mesh2d.shelf_bathymetry(0.3 * cell.depth, cell.depth, cell.lx)
-    geom = geometry.geom2d_from_mesh(m)
+    geom = geometry.geom2d_from_mesh(m, dtype=dtype)
     pts = np.stack([np.asarray(geom.node_x).ravel(),
                     np.asarray(geom.node_y).ravel()], axis=1)
-    b = bf(pts).reshape(3, m.nt).astype(np.float32)
+    b = bf(pts).reshape(3, m.nt).astype(dtype)
     cfg = stepper.OceanConfig(
         nl=cell.nl, dt=cell.dt, m_2d=cell.m_2d, coriolis_f=-4e-5,
         eos_kind="jackett", use_gls=True,
         halo_exchange_period=cell.halo_exchange_period)
-    do = DistributedOcean(m, b, cfg, device_mesh,
-                          axes=device_mesh.axis_names)
-    return do
+    return m, geom, b, cfg
+
+
+def build_cell(cell: OceanCell, device_mesh):
+    m, _, b, cfg = cell_setup(cell)
+    return DistributedOcean(m, b, cfg, device_mesh,
+                            axes=device_mesh.axis_names)
 
 
 def lower_ocean(config_name: str, device_mesh):

@@ -24,9 +24,13 @@ import jax.numpy as jnp
 
 from ..core import dg2d, geometry, mesh2d, stepper
 from ..core.extrusion import VGrid
+from ..obs import diagnostics as obs_diag
+from ..obs import metrics as obs_metrics
 from ..runtime import chaos
 from ..runtime.fault_tolerance import (LadderConfig, RunnerConfig,
                                        SimulationRunner)
+from . import compile_cache
+from .ocean_dryrun import OceanCell, cell_setup
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,31 +44,52 @@ class Case:
 def build_case(nx: int = 6, ny: int = 5, lx: float = 2000.0,
                ly: float = 1500.0, depth: float = 20.0, nl: int = 4,
                dt: float = 5.0, m_2d: int = 6, amp: float = 0.05,
-               dtype=jnp.float64, seed: int = 3) -> Case:
-    """Tiny standing-wave case (the obs-smoke configuration)."""
-    m = mesh2d.rect_mesh(nx, ny, lx, ly, jitter=0.2, seed=seed)
-    geom = geometry.geom2d_from_mesh(m, dtype=dtype)
-    cfg = stepper.OceanConfig(dt=dt, nl=nl, m_2d=m_2d)
-    vg = VGrid(b=jnp.full((3, m.nt), depth, dtype), nl=nl)
+               dtype=jnp.float64, seed: int = 3,
+               cell: Optional[OceanCell] = None) -> Case:
+    """Standing-wave case over a temperature front.
+
+    Without ``cell``: the tiny flat-bottom obs-smoke configuration built
+    from the other arguments.  With ``cell`` (an ``OCEAN_CELLS`` entry or a
+    ``chip_share`` of one): that cell's mesh, bathymetry and OceanConfig,
+    and ``nx`` .. ``m_2d`` and ``seed`` are ignored.  Either way the
+    surface carries a basin-scale standing wave of amplitude ``amp`` and
+    the temperature a 4 K front across the middle of the basin, so the
+    external mode, advection, the EOS, the pressure gradient and GLS all
+    have work from the first step."""
+    if cell is None:
+        m = mesh2d.rect_mesh(nx, ny, lx, ly, jitter=0.2, seed=seed)
+        geom = geometry.geom2d_from_mesh(m, dtype=dtype)
+        cfg = stepper.OceanConfig(dt=dt, nl=nl, m_2d=m_2d)
+        b = jnp.full((3, m.nt), depth, dtype)
+    else:
+        _, geom, b, cfg = cell_setup(cell, dtype)
+        lx = cell.lx
+    vg = VGrid(b=jnp.asarray(b), nl=cfg.nl)
     st = stepper.init_state(geom, vg, dtype=dtype)
     eta = (amp * jnp.cos(jnp.pi * geom.node_x / lx)).astype(dtype)
-    st = dataclasses.replace(st, ext=dg2d.State2D(eta, st.ext.qx, st.ext.qy))
+    front = (10.0 + 4.0 * jnp.tanh((0.5 * lx - geom.node_x)
+                                   / (0.1 * lx))).astype(dtype)
+    T = jnp.broadcast_to(jnp.concatenate([front, front])[None], st.T.shape)
+    st = dataclasses.replace(st, ext=dg2d.State2D(eta, st.ext.qx, st.ext.qy),
+                             T=T)
     return Case(geom=geom, vg=vg, cfg=cfg, state=st)
+
+
+# (geom, vg, cfg, state) -> (state, Diagnostics); the geometry is an
+# argument, not a constant baked into the program, and each OceanConfig
+# (each dt-ladder rung) compiles once
+step_program = jax.jit(obs_diag.step_with_diagnostics, static_argnums=2)
 
 
 def make_step_factory(case: Case) -> Callable:
     """step_factory for SimulationRunner: cfg -> jitted
     ``state -> (state, Diagnostics)`` (dt-ladder rungs recompile here)."""
-    from ..obs import diagnostics as obs_diag
-
     def factory(cfg: stepper.OceanConfig):
-        return jax.jit(lambda s: obs_diag.step_with_diagnostics(
-            case.geom, case.vg, cfg, s))
+        return lambda s: step_program(case.geom, case.vg, cfg, s)
     return factory
 
 
 def default_policy(cfl_max: float = 1.0):
-    from ..obs import diagnostics as obs_diag
     return obs_diag.MonitorPolicy(cfl_max=cfl_max, on_violation="halt")
 
 
@@ -108,14 +133,20 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0, help="FaultPlan seed")
     ap.add_argument("--metrics", default=None,
                     help="JSONL metrics sink path")
+    ap.add_argument("--dtype", choices=("float32", "float64"),
+                    default="float32",
+                    help="float32 is the TPU path; float64 turns on x64 "
+                         "(CPU only: Mosaic takes no f64)")
     args = ap.parse_args(argv)
 
-    jax.config.update("jax_enable_x64", True)
-    from ..obs import metrics as obs_metrics
+    compile_cache.configure()
+    if args.dtype == "float64":
+        jax.config.update("jax_enable_x64", True)
     if args.metrics:
         obs_metrics.configure(args.metrics)
 
-    case = build_case(nx=args.nx, ny=args.ny, nl=args.nl, dt=args.dt)
+    case = build_case(nx=args.nx, ny=args.ny, nl=args.nl, dt=args.dt,
+                      dtype=jnp.dtype(args.dtype))
     runner_cfg = RunnerConfig(checkpoint_dir=args.ckpt,
                               checkpoint_every=args.ckpt_every,
                               max_retries=args.max_retries,

@@ -74,10 +74,12 @@ def compute(geom: G.Geom2D, vg: VGrid, cfg: stepper.OceanConfig,
 
     # conservation integrals: ∫ of a P1 field over a triangle is
     # area * mean(vertex values); tracer content uses the same 3D mass
-    # matrix the stepper conserves with
+    # matrix the stepper conserves with, contracted in full f32 on a TPU
+    # too, whatever the step's own precision
     volume = (geom.area * vge.H.mean(axis=0)).sum()
-    mass_T = vertical.mass_apply3d(geom, vge.jz, st.T).sum()
-    mass_S = vertical.mass_apply3d(geom, vge.jz, st.S).sum()
+    with jax.default_matmul_precision("highest"):
+        mass_T = vertical.mass_apply3d(geom, vge.jz, st.T).sum()
+        mass_S = vertical.mass_apply3d(geom, vge.jz, st.S).sum()
 
     speed2 = st.ux ** 2 + st.uy ** 2
     speed_max = jnp.sqrt(speed2.max())

@@ -240,7 +240,7 @@ def test_corrupt_leaf_and_latest_injectors(tmp_path):
 # halo-exchange payload corruption (trace-time site)
 # ---------------------------------------------------------------------------
 def test_halo_payload_chaos_site():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.distributed import halo
 
@@ -253,7 +253,7 @@ def test_halo_payload_chaos_site():
     def f(xs):
         return halo.exchange(xs[0], t)[None]
     run = lambda: jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"),
-                                    out_specs=P("x"), check_rep=False))(x)
+                                    out_specs=P("x"), check_vma=False))(x)
     clean = np.asarray(run())
     np.testing.assert_array_equal(clean[0], [1.0, 2.0, 1.0, 2.0])
 
@@ -261,7 +261,7 @@ def test_halo_payload_chaos_site():
     with chaos.active(plan):                    # armed during TRACING
         poisoned = np.asarray(jax.jit(shard_map(
             lambda xs: halo.exchange(xs[0] * 1.0, t)[None], mesh=mesh,
-            in_specs=P("x"), out_specs=P("x"), check_rep=False))(x))
+            in_specs=P("x"), out_specs=P("x"), check_vma=False))(x))
     assert np.isnan(poisoned[0, 2:]).all()      # halo slots poisoned
     np.testing.assert_array_equal(poisoned[0, :2], [1.0, 2.0])  # owned intact
     assert plan.log[0]["kind"] == "halo_nan"

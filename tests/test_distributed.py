@@ -62,6 +62,20 @@ def test_scatter_gather_roundtrip():
     np.testing.assert_array_equal(back, f)
 
 
+@pytest.mark.parametrize("backend", ["pallas", "pallas_interpret"])
+def test_distributed_rejects_kernel_backends(backend):
+    """Kernels do not run under shard_map yet: asking for them is an error,
+    not a silent switch to the reference."""
+    import jax
+    from repro.core import stepper
+    from repro.distributed.ocean import DistributedOcean
+    m = mesh2d.rect_mesh(4, 4, 1.0, 1.0, jitter=0.2, seed=1)
+    dmesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",))
+    cfg = stepper.OceanConfig(nl=2, backend=backend)
+    with pytest.raises(ValueError, match="backend 'ref' only"):
+        DistributedOcean(m, np.full((3, m.nt), 10.0), cfg, dmesh, ("x",))
+
+
 # ---------------------------------------------------------------------------
 # full equivalence in a subprocess with 8 spoofed devices
 # ---------------------------------------------------------------------------

@@ -1,0 +1,86 @@
+"""Compile rehearsals for a TPU v5e that is described, not attached.
+
+The four ocean kernels are compiled with ``interpret=False`` at the widths of
+the one-chip benchmark cell (``chip_smoke.py``): nl=32 and 52,480 columns,
+times the number of fields each call folds into its lanes.  A kernel that
+Mosaic cannot lower (a scatter, a sublane concatenate, a misaligned slice,
+too much VMEM) fails here, at no chip time.  The topology is described only
+inside the fixture, never while a module is imported, so that several test
+workers each collect the same tests and only one loads the TPU library.
+"""
+import importlib.util
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.launch import compile_cache
+
+ROOT = Path(__file__).resolve().parents[1]
+NL = 32
+NT = 52_480          # chip_share(OCEAN_CELLS["benchmark"], 4)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # what this compiles cannot be read back without a chip: keep it out
+    # of any persistent cache the environment names
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _f32(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _load_chip_smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+chip_smoke = _load_chip_smoke()
+
+
+@pytest.mark.parametrize("name", ["block_thomas_cell", "lateral_flux_cell",
+                                  "solve_r_cell", "solve_w_cell"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    kernel, _, shapes = chip_smoke.kernel_cases(NT, NL)[name]
+    args = [_f32(one_chip, *s) for s in shapes]
+    # the chip path runs with x64 off (Mosaic takes no 64-bit types); the
+    # suite turns it on
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda *a: kernel(*a, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_chip_smoke_needs_tpu_and_cache_follows_env(monkeypatch, tmp_path):
+    if jax.default_backend() == "tpu":
+        pytest.skip("the device check is exercised where there is no TPU")
+    with pytest.raises(SystemExit, match="no TPU"):
+        chip_smoke.check_device()
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.cache_dir() == str(ROOT / ".jax_cache")
