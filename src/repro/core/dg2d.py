@@ -103,63 +103,72 @@ def external_rhs(geom: G.Geom2D, b: jax.Array, st: State2D,
     (n.{Q} + c+[[eta]]) at the edge Gauss points, (3, 2, nt) — accumulated by
     `run_external` into Fbar_edge for the exact-consistency 3D fluxes."""
     g = G.G_GRAV
-    H = jnp.maximum(st.eta + b, h_min)
+    # named for the trace: the edge values (the burst's gathers) apart from
+    # the rest of the right-hand side; H stays first, where the compiled
+    # loop's carried values expect it
+    with jax.named_scope("burst.assembly"):
+        H = jnp.maximum(st.eta + b, h_min)
+    with jax.named_scope("burst.edge_states"):
+        (ei, qxi, qyi), (ee, qxe, qye) = _edge_states(geom, st, forcing)
+        Hi = ei + G.edge_interp(b)
+        He = ee + G.edge_interp(b)  # bathymetry continuous-ish; ghost: own b
+        Hi = jnp.maximum(Hi, h_min)
+        He = jnp.maximum(He, h_min)
+    with jax.named_scope("burst.assembly"):
+        nx = geom.edge_nx[:, None, :]
+        ny = geom.edge_ny[:, None, :]
 
-    (ei, qxi, qyi), (ee, qxe, qye) = _edge_states(geom, st, forcing)
-    Hi = ei + G.edge_interp(b)
-    He = ee + G.edge_interp(b)  # bathymetry continuous-ish; ghost uses own b
-    Hi = jnp.maximum(Hi, h_min)
-    He = jnp.maximum(He, h_min)
-    nx = geom.edge_nx[:, None, :]
-    ny = geom.edge_ny[:, None, :]
+        c_plus = jnp.sqrt(g * jnp.maximum(Hi, He))
+        jump_eta = 0.5 * (ei - ee)
+        jump_qx = 0.5 * (qxi - qxe)
+        jump_qy = 0.5 * (qyi - qye)
+        mean_qn = 0.5 * ((qxi + qxe) * nx + (qyi + qye) * ny)
+        mean_H = 0.5 * (Hi + He)
 
-    c_plus = jnp.sqrt(g * jnp.maximum(Hi, He))
-    jump_eta = 0.5 * (ei - ee)
-    jump_qx = 0.5 * (qxi - qxe)
-    jump_qy = 0.5 * (qyi - qye)
-    mean_qn = 0.5 * ((qxi + qxe) * nx + (qyi + qye) * ny)
-    mean_H = 0.5 * (Hi + He)
+        # ----- free surface -------------------------------------------------
+        # volume: <grad(phi) . Q>  (Q is P1: mean over qps exact)
+        qx_q = G.vol_interp(st.qx)
+        qy_q = G.vol_interp(st.qy)
+        # sum_q (A/3) * dphi_n . Q(q):
+        vol_eta = (geom.area / 3.0) * (
+            geom.dphi[:, 0, :] * qx_q.sum(axis=0)
+            + geom.dphi[:, 1, :] * qy_q.sum(axis=0))
+        eta_edge_flux = mean_qn + c_plus * jump_eta
+        edge_eta = G.edge_scatter(geom, eta_edge_flux)
+        rhs_eta = vol_eta - edge_eta
+        if forcing.source is not None:
+            rhs_eta = rhs_eta + G.mass_apply(geom, forcing.source)
 
-    # ----- free surface -----------------------------------------------------
-    # volume: <grad(phi) . Q>  (Q is P1: mean over qps exact)
-    qx_q = G.vol_interp(st.qx)
-    qy_q = G.vol_interp(st.qy)
-    # sum_q (A/3) * dphi_n . Q(q):
-    vol_eta = (geom.area / 3.0) * (
-        geom.dphi[:, 0, :] * qx_q.sum(axis=0)
-        + geom.dphi[:, 1, :] * qy_q.sum(axis=0))
-    eta_edge_flux = mean_qn + c_plus * jump_eta
-    edge_eta = G.edge_scatter(geom, eta_edge_flux)
-    rhs_eta = vol_eta - edge_eta
-    if forcing.source is not None:
-        rhs_eta = rhs_eta + G.mass_apply(geom, forcing.source)
+        # ----- momentum -----------------------------------------------------
+        # volume: -<g phi H grad(eta)>  (grad(eta) const per tri; H at qps)
+        deta = G.grad2d(geom, st.eta)                  # (2, nt)
+        H_q = G.vol_interp(H)                          # (3, nt) at qps
+        vol_qx = -g * G.vol_scatter(geom, H_q * deta[0][None, :])
+        vol_qy = -g * G.vol_scatter(geom, H_q * deta[1][None, :])
+        # edges: + <<n phi g {H}[[eta]]>> - <<phi c+ [[Q]]>>
+        edge_qx = G.edge_scatter(
+            geom, nx * g * mean_H * jump_eta - c_plus * jump_qx)
+        edge_qy = G.edge_scatter(
+            geom, ny * g * mean_H * jump_eta - c_plus * jump_qy)
+        rhs_qx = vol_qx + edge_qx
+        rhs_qy = vol_qy + edge_qy
 
-    # ----- momentum -----------------------------------------------------------
-    # volume: -<g phi H grad(eta)>  (grad(eta) const per tri; H at qps)
-    deta = G.grad2d(geom, st.eta)                  # (2, nt)
-    H_q = G.vol_interp(H)                          # (3, nt) at qps
-    vol_qx = -g * G.vol_scatter(geom, H_q * deta[0][None, :])
-    vol_qy = -g * G.vol_scatter(geom, H_q * deta[1][None, :])
-    # edges: + <<n phi g {H}[[eta]]>> - <<phi c+ [[Q]]>>
-    edge_qx = G.edge_scatter(geom, nx * g * mean_H * jump_eta - c_plus * jump_qx)
-    edge_qy = G.edge_scatter(geom, ny * g * mean_H * jump_eta - c_plus * jump_qy)
-    rhs_qx = vol_qx + edge_qx
-    rhs_qy = vol_qy + edge_qy
+        if forcing.patm is not None:
+            dp = G.grad2d(geom, forcing.patm)
+            rhs_qx = rhs_qx - G.vol_scatter(geom,
+                                            H_q * dp[0][None, :] / RHO0)
+            rhs_qy = rhs_qy - G.vol_scatter(geom,
+                                            H_q * dp[1][None, :] / RHO0)
+        if forcing.tau_x is not None:
+            rhs_qx = rhs_qx + G.mass_apply(geom, forcing.tau_x)
+            rhs_qy = rhs_qy + G.mass_apply(geom, forcing.tau_y)
+        if f3d2d_x is not None:
+            rhs_qx = rhs_qx + f3d2d_x
+            rhs_qy = rhs_qy + f3d2d_y
 
-    if forcing.patm is not None:
-        dp = G.grad2d(geom, forcing.patm)
-        rhs_qx = rhs_qx - G.vol_scatter(geom, H_q * dp[0][None, :] / RHO0)
-        rhs_qy = rhs_qy - G.vol_scatter(geom, H_q * dp[1][None, :] / RHO0)
-    if forcing.tau_x is not None:
-        rhs_qx = rhs_qx + G.mass_apply(geom, forcing.tau_x)
-        rhs_qy = rhs_qy + G.mass_apply(geom, forcing.tau_y)
-    if f3d2d_x is not None:
-        rhs_qx = rhs_qx + f3d2d_x
-        rhs_qy = rhs_qy + f3d2d_y
-
-    out = State2D(G.minv_apply(geom, rhs_eta),
-                  G.minv_apply(geom, rhs_qx),
-                  G.minv_apply(geom, rhs_qy))
+        out = State2D(G.minv_apply(geom, rhs_eta),
+                      G.minv_apply(geom, rhs_qx),
+                      G.minv_apply(geom, rhs_qy))
     if return_flux:
         return out, eta_edge_flux
     return out
@@ -239,21 +248,25 @@ def run_external(geom: G.Geom2D, b: jax.Array, st0: State2D, dt: float,
         r, eflux = external_rhs(geom, b, s, forcing, f3d2d_x, f3d2d_y, h_min,
                                 return_flux=True)
         if coriolis_f != 0.0 or bottom_cd > 0.0:
-            r = r + standalone_extra_rhs(geom, b, s, coriolis_f, bottom_cd,
-                                         h_min)
+            with jax.named_scope("burst.assembly"):
+                r = r + standalone_extra_rhs(geom, b, s, coriolis_f,
+                                             bottom_cd, h_min)
         return r, eflux
 
     def substep(s):
         r0, ef0 = rhs(s)
-        s1 = s + dts * r0
+        with jax.named_scope("burst.assembly"):
+            s1 = s + dts * r0
         r1, ef1 = rhs(s1)
-        s2 = 0.75 * s + 0.25 * (s1 + dts * r1)
+        with jax.named_scope("burst.assembly"):
+            s2 = 0.75 * s + 0.25 * (s1 + dts * r1)
         r2, ef2 = rhs(s2)
-        s3 = (1.0 / 3.0) * s + (2.0 / 3.0) * (s2 + dts * r2)
-        w0, w1, w2 = _SSP_W
-        qx_eff = w0 * s.qx + w1 * s1.qx + w2 * s2.qx
-        qy_eff = w0 * s.qy + w1 * s1.qy + w2 * s2.qy
-        ef_eff = w0 * ef0 + w1 * ef1 + w2 * ef2
+        with jax.named_scope("burst.assembly"):
+            s3 = (1.0 / 3.0) * s + (2.0 / 3.0) * (s2 + dts * r2)
+            w0, w1, w2 = _SSP_W
+            qx_eff = w0 * s.qx + w1 * s1.qx + w2 * s2.qx
+            qy_eff = w0 * s.qy + w1 * s1.qy + w2 * s2.qy
+            ef_eff = w0 * ef0 + w1 * ef1 + w2 * ef2
         return s3, (qx_eff, qy_eff, ef_eff)
 
     if exchange_fn is not None and exchange_period > 0:
@@ -264,8 +277,9 @@ def run_external(geom: G.Geom2D, b: jax.Array, st0: State2D, dt: float,
             for _ in range(exchange_period):   # unrolled burst
                 s, acc = substep(s)
                 accs.append(acc)
-            mean = tuple(sum(a[i] for a in accs) / exchange_period
-                         for i in range(3))
+            with jax.named_scope("burst.assembly"):
+                mean = tuple(sum(a[i] for a in accs) / exchange_period
+                             for i in range(3))
             return s, mean
         st1, (qxs, qys, efs) = jax.lax.scan(
             body, st0, None, length=m // exchange_period)

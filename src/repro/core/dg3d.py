@@ -79,10 +79,11 @@ def edge_ext_nodal6(geom: G.Geom2D, f: jax.Array) -> jax.Array:
     linear map of these (see `lat_ext_from_nodal`), so the fused pipeline
     gathers once here instead of at 12-qp width."""
     ft, fb = f[..., 0:3, :], f[..., 3:6, :]
-    ta = ft[..., geom.ext_na, geom.ext_tri]
-    tb = ft[..., geom.ext_nb, geom.ext_tri]
-    ba = fb[..., geom.ext_na, geom.ext_tri]
-    bb = fb[..., geom.ext_nb, geom.ext_tri]
+    with jax.named_scope("gather.neighbour"):
+        ta = ft[..., geom.ext_na, geom.ext_tri]
+        tb = ft[..., geom.ext_nb, geom.ext_tri]
+        ba = fb[..., geom.ext_na, geom.ext_tri]
+        bb = fb[..., geom.ext_nb, geom.ext_tri]
     return jnp.stack([jnp.stack([ta, ba], axis=-2),
                       jnp.stack([tb, bb], axis=-2)], axis=-3)
 
@@ -91,8 +92,9 @@ def own_nodal6(f: jax.Array) -> jax.Array:
     """Own nodal values in the edge_ext_nodal6 layout (identity 'gather');
     used to blend forced open-boundary values at nodal level."""
     ft, fb = f[..., 0:3, :], f[..., 3:6, :]
-    ta, tb = ft[..., G.EDGE_A, :], ft[..., G.EDGE_B, :]
-    ba, bb = fb[..., G.EDGE_A, :], fb[..., G.EDGE_B, :]
+    with jax.named_scope("gather.edge_local"):
+        ta, tb = ft[..., G.EDGE_A, :], ft[..., G.EDGE_B, :]
+        ba, bb = fb[..., G.EDGE_A, :], fb[..., G.EDGE_B, :]
     return jnp.stack([jnp.stack([ta, ba], axis=-2),
                       jnp.stack([tb, bb], axis=-2)], axis=-3)
 
@@ -480,8 +482,9 @@ def _gather_ext_grad(geom: G.Geom2D, gradf: jax.Array) -> jax.Array:
     gradf: (k, nl, 2qz, 2comp, nt) constant-per-triangle gradients.
     Returns (k, nl, 2qz, 3edge, nt): n_ours . grad_ext.
     """
-    ge_x = gradf[..., 0, :][..., geom.ext_tri]          # (k,nl,2qz,3,nt)
-    ge_y = gradf[..., 1, :][..., geom.ext_tri]
+    with jax.named_scope("gather.neighbour"):
+        ge_x = gradf[..., 0, :][..., geom.ext_tri]      # (k,nl,2qz,3,nt)
+        ge_y = gradf[..., 1, :][..., geom.ext_tri]
     return ge_x * geom.edge_nx + ge_y * geom.edge_ny
 
 
@@ -490,7 +493,9 @@ def sigma3_lateral(geom: G.Geom2D, N0: float = 5.0, o: int = 1,
     """Interior-penalty coefficient on lateral faces (eq. 19): L = A/l."""
     L_int = geom.area[None, :] / geom.edge_len          # (3, nt)
     # exterior L: neighbour's area over the same (shared) edge length
-    L_ext = geom.area[geom.ext_tri] / geom.edge_len
+    with jax.named_scope("gather.neighbour"):
+        area_ext = geom.area[geom.ext_tri]
+    L_ext = area_ext / geom.edge_len
     return N0 * (o + 1) * (o + d) / (2.0 * d * jnp.minimum(L_int, L_ext))
 
 

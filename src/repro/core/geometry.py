@@ -181,16 +181,18 @@ def edge_interp(f: jax.Array) -> jax.Array:
 
     f: (..., 3, nt) nodal -> (..., 3, 2, nt) [edge, qp].
     """
-    fa = f[..., EDGE_A, :]          # (..., 3, nt)
-    fb = f[..., EDGE_B, :]
+    with jax.named_scope("gather.edge_local"):
+        fa = f[..., EDGE_A, :]      # (..., 3, nt)
+        fb = f[..., EDGE_B, :]
     return (fa[..., :, None, :] * _PHIA[:, None]
             + fb[..., :, None, :] * _PHIB[:, None])
 
 
 def edge_ext_nodal(geom: Geom2D, f: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Neighbour nodal values facing my edge nodes a and b: two (..., 3, nt)."""
-    fa = f[..., geom.ext_na, geom.ext_tri]
-    fb = f[..., geom.ext_nb, geom.ext_tri]
+    with jax.named_scope("gather.neighbour"):
+        fa = f[..., geom.ext_na, geom.ext_tri]
+        fb = f[..., geom.ext_nb, geom.ext_tri]
     return fa, fb
 
 

@@ -81,8 +81,9 @@ def lateral_flux_cell(f, fext, speed, wq):
     fzi = jnp.einsum("zv,lvnc->lznc", PZ, ff)      # interior nodal at qz
     fze = jnp.einsum("zv,lejvc->lzejc", PZ, ext)   # exterior per edge at qz
     # edge s-interp -> (l, qz, e, qs, C)
-    fia = fzi[..., np.asarray(G.EDGE_A), :]
-    fib = fzi[..., np.asarray(G.EDGE_B), :]
+    with jax.named_scope("gather.edge_local"):
+        fia = fzi[..., np.asarray(G.EDGE_A), :]
+        fib = fzi[..., np.asarray(G.EDGE_B), :]
     fi = fia[..., None, :] * pa[:, None] + fib[..., None, :] * pb[:, None]
     fe = (fze[..., 0, :][..., None, :] * pa[:, None]
           + fze[..., 1, :][..., None, :] * pb[:, None])

@@ -4,7 +4,7 @@ A ``Registry`` owns named instruments and (optionally) a ``JsonlSink``:
 
     reg = metrics.Registry(sink=metrics.JsonlSink(path))
     reg.counter("kernel_dispatch", op="solve_r", backend="pallas").inc()
-    reg.gauge("runner.step_time_ema_s").set(0.12)
+    reg.gauge("sim.ladder.rung").set(1)
     with reg.timer("stage_time_us", stage="step"):
         ...                         # host wall time -> histogram observe
     reg.event("monitor.violation", {"rule": "cfl"}, step=3)   # immediate

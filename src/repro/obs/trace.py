@@ -1,11 +1,12 @@
-"""Staged tracing helpers: named scopes for XLA/Pallas profiles + an opt-in
+"""Staged tracing helpers: host spans for profiler traces + an opt-in
 ``jax.profiler`` trace session that lands in a run directory.
 
-``annotate(name)`` is safe both inside jit-traced code (adds a
-``jax.named_scope`` so the op shows up under that name in compiled HLO and
-profiler timelines) and on the host (adds a ``TraceAnnotation`` span to any
-active profiler trace).  The library hot path uses bare ``jax.named_scope``
-directly — zero runtime cost, pure trace-time metadata.
+``span(name)`` marks a stretch of host time in any active profiler trace,
+on the clock of the device ops (``jax.profiler.TraceAnnotation``; with
+``step`` a ``StepTraceAnnotation``, by which xprof groups the device ops of
+a step).  It costs next to nothing when no trace is active.  Traced library
+code names its ops with bare ``jax.named_scope`` instead — zero runtime
+cost, pure trace-time metadata in the compiled HLO.
 
 ``trace_session`` wraps ``jax.profiler.start_trace/stop_trace``; it is
 opt-in: enabled explicitly, or via the ``REPRO_TRACE=1`` environment
@@ -39,11 +40,13 @@ def default_run_dir(prefix: str = "trace") -> str:
     return os.path.join(DEFAULT_RUNS_ROOT, f"{prefix}-{stamp}-{os.getpid()}")
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """named_scope (trace-time HLO metadata) + TraceAnnotation (host span)."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+def span(name: str, step: Optional[int] = None):
+    """A host span ``name`` in any active profiler trace; with ``step``, the
+    span of that step (``step_num``).  Host code only: inside traced code it
+    would time the tracing, not the ops."""
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 @contextlib.contextmanager

@@ -46,6 +46,7 @@ import numpy as np
 
 from ..checkpoint.checkpoint import CheckpointError, Checkpointer
 from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from . import chaos
 
 
@@ -104,6 +105,14 @@ def _diag_nonfinite(diag: Any) -> Optional[str]:
     if flag is None or not bool(flag):
         return None
     return f"non-finite state (field={field}, cell={cell})"
+
+
+def _host_diag(diag: Any) -> Any:
+    """A step's ``Diagnostics`` as host scalars (``obs.diagnostics.to_dict``:
+    one copy of the whole bundle, which waits for the step); anything else
+    as it is."""
+    from ..obs.diagnostics import Diagnostics, to_dict
+    return to_dict(diag) if isinstance(diag, Diagnostics) else diag
 
 
 def _diag_value(diag: Any, key: str) -> Optional[float]:
@@ -174,8 +183,6 @@ class _RunnerBase:
         reg = self._reg()
         if reg is not None:
             reg.histogram("runner.step_time_s").observe(dt)
-            reg.gauge("runner.step_time_ema_s").set(
-                self.stats["step_time_ema"])
 
     # -------------------------------------------------------- checkpoints
     def _save(self, step: int, state: Any, blocking: bool = False):
@@ -326,8 +333,9 @@ class SimulationRunner(_RunnerBase):
 
     def _step_fn(self) -> Callable:
         if self.rung not in self._step_fns:
-            self._step_fns[self.rung] = self.step_factory(
-                self._cfg_for_rung(self.rung))
+            with obs_trace.span("runner.build_step"):
+                self._step_fns[self.rung] = self.step_factory(
+                    self._cfg_for_rung(self.rung))
         return self._step_fns[self.rung]
 
     def _transition(self, rung: int, step: int, reason: str):
@@ -381,43 +389,53 @@ class SimulationRunner(_RunnerBase):
             chaos.site("runner.step", step=step)
             if self._preempt:
                 break
-            t0 = time.time()
-            try:
-                st_in = chaos.site("sim.state", state, step=step)
-                new_state, diag = self._step_fn()(st_in)
-                if self.policy is not None:
-                    self.policy.check(diag, step=step, registry=reg)
-                reason = _diag_nonfinite(diag)
-                if self.cfg.nan_is_failure and reason is not None:
-                    raise FloatingPointError(f"{reason} at {step}")
-            except Exception as e:
-                retries += 1
-                self.stats["retries"] += 1
-                self._count("runner.retries")
-                if retries > self.cfg.max_retries:
-                    self._drain()
-                    raise
-                if reg is not None:
-                    reg.event("sim.recovery", {"step": step, "retry": retries,
-                                               "error": repr(e)}, step=step)
-                state, step = self._recover(state, start_state, start0)
-                self._transition(min(retries - 1, self.ladder.max_rungs),
-                                 step, reason=repr(e))
-                calm = 0
-                time.sleep(self.cfg.backoff_base_s * 2 ** retries)
-                continue
-            retries = 0
-            state = new_state
-            self._observe_step_time(time.time() - t0)
-            if self.rung > 0:
-                calm = calm + 1 if self._calm(diag) else 0
-                if calm >= self.ladder.recover_steps:
-                    self._transition(self.rung - 1, step, reason="recovered")
+            t0 = time.perf_counter()
+            # host spans of the iteration, on the profiler's clock: the
+            # device's idle gaps between steps fall inside one of them
+            with obs_trace.span("runner.step", step=step):
+                try:
+                    st_in = chaos.site("sim.state", state, step=step)
+                    with obs_trace.span("runner.dispatch"):
+                        new_state, diag = self._step_fn()(st_in)
+                    with obs_trace.span("runner.wait"):
+                        diag = _host_diag(diag)
+                    with obs_trace.span("runner.check"):
+                        if self.policy is not None:
+                            self.policy.check(diag, step=step, registry=reg)
+                        reason = _diag_nonfinite(diag)
+                    if self.cfg.nan_is_failure and reason is not None:
+                        raise FloatingPointError(f"{reason} at {step}")
+                except Exception as e:
+                    retries += 1
+                    self.stats["retries"] += 1
+                    self._count("runner.retries")
+                    if retries > self.cfg.max_retries:
+                        self._drain()
+                        raise
+                    if reg is not None:
+                        reg.event("sim.recovery",
+                                  {"step": step, "retry": retries,
+                                   "error": repr(e)}, step=step)
+                    state, step = self._recover(state, start_state, start0)
+                    self._transition(min(retries - 1, self.ladder.max_rungs),
+                                     step, reason=repr(e))
                     calm = 0
-            step += 1
-            self.stats["steps"] += 1
-            if step % self.cfg.checkpoint_every == 0:
-                self._save(step, state)
+                    time.sleep(self.cfg.backoff_base_s * 2 ** retries)
+                    continue
+                retries = 0
+                state = new_state
+                self._observe_step_time(time.perf_counter() - t0)
+                if self.rung > 0:
+                    calm = calm + 1 if self._calm(diag) else 0
+                    if calm >= self.ladder.recover_steps:
+                        self._transition(self.rung - 1, step,
+                                         reason="recovered")
+                        calm = 0
+                step += 1
+                self.stats["steps"] += 1
+                if step % self.cfg.checkpoint_every == 0:
+                    with obs_trace.span("runner.checkpoint"):
+                        self._save(step, state)
         if self._preempt:
             self.stats["preempted"] = True
             self._save(step, state, blocking=True)

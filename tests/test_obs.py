@@ -250,6 +250,80 @@ def test_runner_accepts_diagnostics_pytree(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# SimulationRunner: host spans in a profiler trace, one host copy per step
+# ---------------------------------------------------------------------------
+class _SpyPolicy:
+    def __init__(self):
+        self.seen = []
+
+    def check(self, diag, step=None, registry=None):
+        self.seen.append(diag)
+        return []
+
+
+def _runner_spans(trace_dir):
+    """(start_ns, duration_ns, name, stats) of the ``runner.*`` host spans
+    of the one profile under ``trace_dir``, in start order."""
+    from jax.profiler import ProfileData
+    [path] = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return sorted((ev.start_ns, ev.duration_ns, ev.name, dict(ev.stats))
+                  for plane in pd.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("runner."))
+
+
+def test_runner_spans_in_profiler_trace(tmp_path):
+    from repro.launch import sim_campaign
+    from repro.runtime.fault_tolerance import SimulationRunner
+    case = sim_campaign.build_case(nx=4, ny=3)
+    policy = _SpyPolicy()
+    runner = SimulationRunner(
+        sim_campaign.make_step_factory(case), case.cfg,
+        RunnerConfig(checkpoint_dir=str(tmp_path / "ckpt"),
+                     checkpoint_every=100, emit_metrics=False),
+        policy=policy)
+    # compiled before the trace: the profiler slows XLA's compile severalfold
+    jax.block_until_ready(sim_campaign.step_program(
+        case.geom, case.vg, case.cfg, case.state))
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        runner.run(case.state, 2, resume=False)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _runner_spans(tmp_path / "trace")
+    steps = [s for s in spans if s[2] == "runner.step"]
+    assert [s[3].get("step_num") for s in steps] == [0, 1]
+    for k, (t0, dur, _, _) in enumerate(steps):
+        inside = [name for t, d, name, _ in spans
+                  if name != "runner.step" and t0 <= t and t + d <= t0 + dur]
+        # the step is built once, inside the first step's dispatch
+        assert inside == (
+            ["runner.dispatch", "runner.build_step", "runner.wait",
+             "runner.check"] if k == 0 else
+            ["runner.dispatch", "runner.wait", "runner.check"]), inside
+    # the policy reads the host copy the runner made, not the device bundle
+    assert len(policy.seen) == 2
+    assert all(isinstance(d, dict) for d in policy.seen)
+    assert runner.stats["step_time_ema"] > 0
+
+
+def test_sim_runner_names_the_nonfinite_field_and_cell(tmp_path):
+    from repro.runtime.fault_tolerance import SimulationRunner
+    _, geom, vg = build(nx=4, ny=3)
+    cfg3 = stepper.OceanConfig(dt=5.0, nl=4, m_2d=6)
+    st = standing_wave_state(geom, vg)
+    bad = dataclasses.replace(st, T=st.T.at[0, 0, 3].set(jnp.nan))
+    diag = obs_diag.compute(geom, vg, cfg3, bad)
+    runner = SimulationRunner(
+        lambda c: (lambda s: (s, diag)), cfg3,
+        RunnerConfig(checkpoint_dir=str(tmp_path), max_retries=0,
+                     emit_metrics=False))
+    with pytest.raises(FloatingPointError, match="field=T, cell=3"):
+        runner.run(st, 1, resume=False)
+
+
+# ---------------------------------------------------------------------------
 # obs_report: bench diff
 # ---------------------------------------------------------------------------
 def test_obs_report_diff_flags_regression(tmp_path, capsys):
