@@ -62,13 +62,14 @@ class Forcing2D:
 
 def _edge_states(geom: G.Geom2D, st: State2D, forcing: Forcing2D):
     """Interior/exterior values of (eta, qx, qy) at the edge Gauss points,
-    with WALL / OPEN ghost states applied."""
+    with WALL / OPEN ghost states applied.  The neighbour values of all three
+    fields come from one gather of their stacked nodal record."""
     ei = G.edge_interp(st.eta)
     qxi = G.edge_interp(st.qx)
     qyi = G.edge_interp(st.qy)
-    ee = G.edge_interp_ext(geom, st.eta)
-    qxe = G.edge_interp_ext(geom, st.qx)
-    qye = G.edge_interp_ext(geom, st.qy)
+    ext_a, ext_b = G.edge_ext_record(geom,
+                                     jnp.stack([st.eta, st.qx, st.qy]))
+    ee, qxe, qye = G.edge_blend(ext_a, ext_b)
 
     nx = geom.edge_nx[:, None, :]
     ny = geom.edge_ny[:, None, :]

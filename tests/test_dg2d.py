@@ -166,3 +166,94 @@ def test_open_boundary_tidal_inflow():
     qx = np.asarray(st.qx)
     assert np.isfinite(qx).all()
     assert qx.mean() > 1e-4  # net +x transport develops
+
+
+# ---------------------------------------------------------------------------
+# the burst's neighbour gather: one record gather of the stacked state
+# ---------------------------------------------------------------------------
+def _edge_states_per_field(geom, st, forcing):
+    """The per-field formula the record gather replaced: two point gathers
+    per field (`edge_interp_ext`), then the same ghost states."""
+    ei = geometry.edge_interp(st.eta)
+    qxi = geometry.edge_interp(st.qx)
+    qyi = geometry.edge_interp(st.qy)
+    ee = geometry.edge_interp_ext(geom, st.eta)
+    qxe = geometry.edge_interp_ext(geom, st.qx)
+    qye = geometry.edge_interp_ext(geom, st.qy)
+    nx = geom.edge_nx[:, None, :]
+    ny = geom.edge_ny[:, None, :]
+    wall = geom.wall[:, None, :]
+    openb = geom.openb[:, None, :]
+    intm = 1.0 - wall - openb
+    qn = qxe * nx + qye * ny
+    qx_wall = qxe - 2.0 * qn * nx
+    qy_wall = qye - 2.0 * qn * ny
+    if forcing.eta_open is not None:
+        eta_open = geometry.edge_interp(forcing.eta_open)
+    else:
+        eta_open = ee
+    eta_e = intm * ee + wall * ei + openb * eta_open
+    qx_e = intm * qxe + wall * qx_wall + openb * qxi
+    qy_e = intm * qye + wall * qy_wall + openb * qyi
+    return (ei, qxi, qyi), (eta_e, qx_e, qy_e)
+
+
+def _channel(dtype, seed=5):
+    """Jittered channel, WALL sides and OPEN ends, with a random state."""
+    mch = mesh2d.channel_mesh(24, 6, 3000.0, 600.0, jitter=0.1)
+    geom = geometry.geom2d_from_mesh(mch, dtype=dtype)
+    key = jax.random.PRNGKey(seed)
+    eta, qx, qy, bc = (jax.random.normal(jax.random.fold_in(key, i),
+                                         (3, mch.nt), dtype=dtype)
+                       for i in range(4))
+    return mch, geom, State2D(0.1 * eta, qx, qy), 0.2 * bc
+
+
+@pytest.mark.parametrize("open_bc", [False, True],
+                         ids=["no_eta_open", "eta_open"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
+                         ids=["f32", "f64"])
+def test_edge_states_match_per_field_gathers(dtype, open_bc):
+    """The record gather gives the per-field formula's edge states, bit for
+    bit, interior and ghost, on WALL and OPEN edges."""
+    with jax.enable_x64(dtype == jnp.float64):     # f32 as on the chip
+        mch, geom, st, bc = _channel(dtype)
+        assert (mch.edge_type == mesh2d.WALL).any()
+        assert (mch.edge_type == mesh2d.OPEN).any()
+        forcing = Forcing2D(eta_open=bc if open_bc else None)
+        got = jax.jit(dg2d._edge_states)(geom, st, forcing)
+        want = jax.jit(_edge_states_per_field)(geom, st, forcing)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["field", "stacked"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
+                         ids=["f32", "f64"])
+def test_edge_ext_record_matches_nodal(dtype, stacked):
+    """`edge_ext_record` returns `edge_ext_nodal`'s two arrays bit for bit,
+    for one (3, nt) field and for a stacked (3, 3, nt) one."""
+    _, geom, st, _ = _channel(dtype, seed=9)
+    f = jnp.stack([st.eta, st.qx, st.qy]) if stacked else st.qx
+    got = geometry.edge_ext_record(geom, f)
+    want = geometry.edge_ext_nodal(geom, f)
+    for g, w in zip(got, want):
+        assert g.shape == f.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_external_rhs_has_one_neighbour_gather():
+    """The burst's right-hand side fetches neighbour values with exactly one
+    gather instruction in the compiled program (six before the stacked
+    record)."""
+    import re
+    mch, geom, st, bc = _channel(jnp.float32)
+    b = jnp.full((3, mch.nt), 10.0, dtype=jnp.float32)
+    hlo = jax.jit(dg2d.external_rhs).lower(
+        geom, b, st, Forcing2D(eta_open=bc)).compile().as_text()
+    gathers = [ln for ln in hlo.splitlines()
+               if re.search(r"\sgather\(", ln)]
+    neighbour = [ln for ln in gathers if "gather.neighbour" in ln]
+    assert len(neighbour) == 1, neighbour
+    assert len(gathers) > 1          # the edge-local selections stay apart
