@@ -12,7 +12,8 @@ The window drives the program's normal path: ``SimulationRunner.run`` over
 ``sim_campaign.step_program`` (the kernel path, the fused horizontal RHS,
 highest matmul precision, diagnostics every step and the halt-mode
 ``default_policy``), whole steps until ``--seconds`` have passed, with no
-checkpoint inside it.
+checkpoint inside it.  A cell on more than one chip runs the program's
+multi-chip entry point instead, ``DistributedOcean`` (``bench/sharded.py``).
 """
 from __future__ import annotations
 
@@ -128,27 +129,40 @@ PHYSICS = ("nl", "dt", "m_2d", "coriolis_f", "cd_bottom", "cs_smag",
            "nu_v_bg", "kappa_v_bg", "use_gls", "matmul_precision")
 
 
-def program_case(conf: dict, fields: dict):
-    """The program's case for ``conf``: ``ocean_dryrun.cell_setup`` of the
-    configuration's sizes, its state with the traffic's fields.  Refuses a
-    program whose physics or mesh departs from what the file states."""
-    import jax.numpy as jnp
-    from repro.core import dg2d, stepper
-    from repro.core.extrusion import VGrid
-    from repro.launch.ocean_dryrun import OceanCell, cell_setup
-    from repro.launch.sim_campaign import Case
-    cell = OceanCell(conf["name"], conf["nx"], conf["ny"], conf["lx"],
-                     conf["ly"], conf["nl"], conf["m_2d"], conf["dt"],
-                     conf["depth"], reef=conf["reef"])
-    m, geom, b, cfg = cell_setup(cell, jnp.float32)
+def same_physics(cfg, conf: dict):
+    """Refuses an ``OceanConfig`` whose physics departs from the file."""
     got = {k: getattr(cfg, k) for k in PHYSICS}
     want = {k: conf[k] for k in PHYSICS}
     if got != want:
         raise ValueError(f"bench: the program's OceanConfig departs from "
                          f"{conf['name']}: {got} != {want}")
+
+
+def program_parts(conf: dict) -> tuple:
+    """(mesh, geometry, nodal bathymetry, OceanConfig) of ``conf``:
+    ``ocean_dryrun.cell_setup`` of the configuration's sizes.  Refuses a
+    program whose physics or triangle count departs from the file."""
+    import jax.numpy as jnp
+    from repro.launch.ocean_dryrun import OceanCell, cell_setup
+    cell = OceanCell(conf["name"], conf["nx"], conf["ny"], conf["lx"],
+                     conf["ly"], conf["nl"], conf["m_2d"], conf["dt"],
+                     conf["depth"], reef=conf["reef"])
+    m, geom, b, cfg = cell_setup(cell, jnp.float32)
+    same_physics(cfg, conf)
     if m.nt != conf["triangles"]:
         raise ValueError(f"bench: {m.nt} triangles, the file states "
                          f"{conf['triangles']}")
+    return m, geom, b, cfg
+
+
+def program_case(conf: dict, fields: dict, parts: Optional[tuple] = None):
+    """The program's case for ``conf`` (``program_parts``, or ``parts`` where
+    the caller has them), its state with the traffic's fields."""
+    import jax.numpy as jnp
+    from repro.core import dg2d, stepper
+    from repro.core.extrusion import VGrid
+    from repro.launch.sim_campaign import Case
+    _, geom, b, cfg = parts or program_parts(conf)
     vg = VGrid(b=jnp.asarray(b), nl=cfg.nl)
     st = stepper.init_state(geom, vg, dtype=jnp.float32)
     f = {k: jnp.asarray(fields[k], jnp.float32)
@@ -159,15 +173,17 @@ def program_case(conf: dict, fields: dict):
     return Case(geom=geom, vg=vg, cfg=cfg, state=st), np.asarray(b)
 
 
-class _Diags:
-    """Wraps the campaign's step factory and keeps the on-device
-    ``Diagnostics`` of step 1 and of step 1 + ``DRIFT_STEPS`` (or the last
-    step, in a shorter run): references only, no copy, no sync.  A fixed
-    count of steps keeps the drift a property of the program, not of how
-    many steps a window holds."""
+class Keep:
+    """Wraps the campaign's step factory and keeps ``pick`` of the step's
+    ``(state, diagnostics)`` (the on-device ``Diagnostics`` by default) of
+    step 1 and of step 1 + ``DRIFT_STEPS`` (or the last step, in a shorter
+    run): references only, no copy, no sync.  A fixed count of steps keeps
+    the drift a property of the program, not of how many steps a window
+    holds."""
 
-    def __init__(self, factory):
+    def __init__(self, factory, pick=lambda out: out[1]):
         self.factory = factory
+        self.pick = pick
         self.seen = 0
         self.first = self.last = None
 
@@ -178,9 +194,9 @@ class _Diags:
             out = fn(s)
             self.seen += 1
             if self.first is None:
-                self.first = out[1]
+                self.first = self.pick(out)
             if self.seen <= 1 + DRIFT_STEPS:
-                self.last = out[1]
+                self.last = self.pick(out)
             return out
         return step
 
@@ -301,6 +317,9 @@ def run(opt: Options) -> dict:
     run_dir = opt.run_dir or (opt.repo / "runs" / "bench" / opt.workload)
     run_dir.mkdir(parents=True, exist_ok=True)
     compiles = _CompileCount()
+    if len(devs) > 1:
+        from . import sharded
+        return sharded.run(opt, cell, devs, run_dir, compiles)
 
     # ------------------------------------------------------------ set-up
     from . import reference
@@ -314,7 +333,6 @@ def run(opt: Options) -> dict:
         backend=case.cfg.backend, fused=case.cfg.fused_horizontal,
         precision=case.cfg.matmul_precision,
         **{k: round(v, 6) for k, v in fields0["draw"].items()})
-    from . import trace_reduce
     tdir = run_dir / "trace"
     # the step is compiled ahead of time in every run and the runner calls
     # that executable: a jit dispatch after an ahead-of-time compile is
@@ -325,20 +343,7 @@ def run(opt: Options) -> dict:
         case.geom, case.vg, case.cfg, case.state).compile()
     say(phase="compile", seconds=round(time.perf_counter() - t0, 3))
     if opt.trace:
-        # the trace names each op by its HLO instruction; the compiled
-        # program's metadata gives the op's name-scope path
-        ma = compiled.memory_analysis()
-        hlo = compiled.as_text()
-        say(phase="program", temp_bytes=ma.temp_size_in_bytes,
-            argument_bytes=ma.argument_size_in_bytes,
-            output_bytes=ma.output_size_in_bytes,
-            alias_bytes=ma.alias_size_in_bytes,
-            tpu_custom_call=hlo.count("tpu_custom_call"))
-        shutil.rmtree(tdir, ignore_errors=True)
-        tdir.mkdir(parents=True)
-        (tdir / trace_reduce.SCOPES_FILE).write_text(
-            json.dumps(trace_reduce.hlo_scopes(hlo)))
-        del hlo
+        program_report(compiled, tdir)
     geom, vg = case.geom, case.vg
 
     def factory(cfg):
@@ -347,7 +352,7 @@ def run(opt: Options) -> dict:
         return lambda s: compiled(geom, vg, s)
     if opt.break_step is not None:
         factory = opt.break_step(factory)
-    diags = _Diags(factory)
+    diags = Keep(factory)
     runner = SimulationRunner(
         diags, case.cfg,
         RunnerConfig(checkpoint_dir=str(run_dir / "ckpt"),
@@ -363,34 +368,9 @@ def run(opt: Options) -> dict:
     jax.block_until_ready(st)
     case = dataclasses.replace(case, state=None)
 
-    # ------------------------------------------------------------ window
-    if opt.trace:
-        jax.profiler.start_trace(str(tdir))
-    steps = failed = 0
-    error = None
-    compiles.on = True
-    t_window = time.perf_counter()
-    setup_s = t_window - opt.t_start
-    with trace_reduce.span("bench.window"):
-        while True:
-            k = N_WARMUP + steps
-            try:
-                with trace_reduce.span("bench.runner_run"):
-                    st = runner.run(st, k + 1, start_step=k, resume=False)
-            except Exception as e:          # a halt or a non-finite step
-                failed += 1
-                error = f"{type(e).__name__}: {e}"
-                break
-            steps += 1
-            if time.perf_counter() - t_window >= opt.seconds:
-                break
-    wall = time.perf_counter() - t_window
-    compiles.on = False
-    if opt.trace:
-        jax.profiler.stop_trace()
-    failed = max(failed, runner.stats["retries"])
-    say(phase="window", steps=steps, failed=failed, wall_s=wall,
-        compiles_in_window=compiles.n, error=repr(error))
+    held = [st]
+    del st
+    st, w = window(runner, held, opt, tdir, compiles)
 
     from repro.obs import diagnostics as obs_diag
     numbers = {}
@@ -411,19 +391,98 @@ def run(opt: Options) -> dict:
     start, ref1 = reference_step1(conf, fields0)
     say(phase="reference", seconds=time.perf_counter() - t0)
     numbers.update(deviation(step1, ref1, start))
+    return finish(opt, cell, devs, numbers, w, memory_peak, tdir)
+
+
+def program_report(compiled, tdir: Path):
+    """In a traced run: the compiled step's memory and, beside the trace,
+    its scopes (the trace names each op by its HLO instruction; the
+    compiled program's metadata gives the op's name-scope path)."""
+    from . import trace_reduce
+    ma = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    say(phase="program", temp_bytes=ma.temp_size_in_bytes,
+        argument_bytes=ma.argument_size_in_bytes,
+        output_bytes=ma.output_size_in_bytes,
+        alias_bytes=ma.alias_size_in_bytes,
+        tpu_custom_call=hlo.count("tpu_custom_call"))
+    shutil.rmtree(tdir, ignore_errors=True)
+    tdir.mkdir(parents=True)
+    (tdir / trace_reduce.SCOPES_FILE).write_text(
+        json.dumps(trace_reduce.hlo_scopes(hlo)))
+
+
+@dataclasses.dataclass
+class Window:
+    steps: int
+    failed: int
+    wall_s: float
+    setup_s: float
+
+
+def window(runner, held: list, opt: Options, tdir: Path,
+           compiles: _CompileCount, sync: Optional[Callable] = None) -> tuple:
+    """(state, Window): whole steps through ``runner`` from the state in
+    ``held`` until ``opt.seconds`` have passed, each inside a
+    ``bench.runner_run`` span that ends with ``sync(state)`` where one is
+    given; traced where ``opt.trace``.  The state is taken out of ``held``
+    so that no caller's reference keeps an earlier step's state alive."""
+    import jax
+    from . import trace_reduce
+    st = held.pop()
+    if opt.trace:
+        jax.profiler.start_trace(str(tdir))
+    steps = failed = 0
+    error = None
+    compiles.on = True
+    t_window = time.perf_counter()
+    setup_s = t_window - opt.t_start
+    with trace_reduce.span("bench.window"):
+        while True:
+            k = N_WARMUP + steps
+            try:
+                with trace_reduce.span("bench.runner_run"):
+                    st = runner.run(st, k + 1, start_step=k, resume=False)
+                    if sync is not None:
+                        sync(st)
+            except Exception as e:          # a halt or a non-finite step
+                failed += 1
+                error = f"{type(e).__name__}: {e}"
+                break
+            steps += 1
+            if time.perf_counter() - t_window >= opt.seconds:
+                break
+    wall = time.perf_counter() - t_window
+    compiles.on = False
+    if opt.trace:
+        jax.profiler.stop_trace()
+    failed = max(failed, runner.stats["retries"])
+    say(phase="window", steps=steps, failed=failed, wall_s=wall,
+        compiles_in_window=compiles.n, error=repr(error))
+    return st, Window(steps, failed, wall, setup_s)
+
+
+def finish(opt: Options, cell: Cell, devs, numbers: dict, w: Window,
+           memory_peak: int, tdir: Path, sound: bool = True) -> dict:
+    """The check's verdict on ``numbers`` (and ``sound``: nothing else
+    failed), the metrics of the window (from the trace, where traced) and
+    the result object, with the numbers compared printed last."""
+    from . import trace_reduce
+    conf, dev = cell.conf, devs[0]
     say(phase="numbers", **numbers)
     correct, rows = judge(numbers, cell.limits)
-    correct = correct and failed == 0 and steps > 0
+    correct = correct and sound and w.failed == 0 and w.steps > 0
 
     # ------------------------------------------------------------ result
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs), "memory_peak_bytes": memory_peak}
     metrics = {}
-    result = {"correct": bool(correct), "attempted": N_WARMUP + steps,
-              "failed": failed, "metrics": metrics, "device": device}
+    result = {"correct": bool(correct), "attempted": N_WARMUP + w.steps,
+              "failed": w.failed, "metrics": metrics, "device": device}
     if not opt.trace:
-        values = {"sim_per_wall": conf["dt"] * steps / wall if steps else 0.0,
-                  "setup_s": setup_s}
+        values = {"sim_per_wall": (conf["dt"] * w.steps / w.wall_s
+                                   if w.steps else 0.0),
+                  "setup_s": w.setup_s}
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
@@ -434,11 +493,13 @@ def run(opt: Options) -> dict:
         other = tr.op_seconds(lambda o: not any(
             c.startswith("stage.") for c in o.scope.split("/")))
         say(phase="reduce", seconds=time.perf_counter() - t0,
-            ops=len(tr.ops), other_ms_per_step=1e3 * other / max(steps, 1))
-        ctx = trace_reduce.Context(conf=conf, steps=steps, wall_s=wall,
+            ops=len(tr.ops),
+            other_ms_per_step=1e3 * other / max(w.steps, 1))
+        ctx = trace_reduce.Context(conf=conf, steps=w.steps, wall_s=w.wall_s,
                                    kind=dev.device_kind,
                                    peaks=lambda: peaks(dev.device_kind,
-                                                       opt.repo / "bench"))
+                                                       opt.repo / "bench"),
+                                   chips=len(devs))
         for m in cell.per_layer:
             v = layer_reader(m["name"], opt.repo / "bench")(tr, ctx)
             if v is not None:

@@ -7,7 +7,12 @@ bytes cover every stage, the external burst's evaluations among them; its
 operations count only the four kernels', a floor, and at the cells' sizes
 the bytes bound the step 28 to 34 times over.  The time is the union of the
 device's op intervals (``trace.busy_s``), so host gaps do not lower it:
-``device.idle_share`` reads those."""
+``device.idle_share`` reads those.
+
+On several chips the busy time is one chip's (averaged over the chips) and
+the work one chip's share of the whole mesh's, ``step(conf)`` over the
+chips: the ghost triangles a partition computes again are not work the step
+needs."""
 from bench import work
 
 
@@ -20,4 +25,4 @@ def read(trace, ctx):
     ctx.notes.append({"step_least_s": lt, "step_bound": bound,
                       "step_flops": flops, "step_bytes": nbytes,
                       "busy_s_per_step": busy_per_step})
-    return 100.0 * lt / busy_per_step
+    return 100.0 * lt / (ctx.chips * busy_per_step)

@@ -60,6 +60,7 @@ class Op:
     dur_ns: float       # its own time: nested ops' time taken out
     device: int
     kernel: bool        # a Pallas kernel (a TPU custom call)
+    end_ns: float       # its end, nested ops' time kept in
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,6 +113,7 @@ class Context:
     wall_s: float                   # host wall time of those steps
     kind: str                       # device_kind
     peaks: Callable[[], dict]       # bench/peaks.json entry; raises if none
+    chips: int = 1                  # devices the cell's step runs on
     notes: List[dict] = dataclasses.field(default_factory=list)
 
 
@@ -186,10 +188,10 @@ def reduce(trace_dir: Path, window_span: str = "bench.window") -> Trace:
                    key=lambda s: s.start_ns)
     for dev, events in raw:
         events = [e for e in events if w0 <= e[1] < w1]
-        for (name, start, _), own in _self_times(events):
+        for (name, start, dur), own in _self_times(events):
             short = op_name(name)
             ops.append(Op(short, scopes.get(short, ""), start, own, dev,
-                          "tpu_custom_call" in name))
+                          "tpu_custom_call" in name, start + dur))
         iv = _union((s, min(s + d, w1)) for _, s, d in events)
         busy_ns += sum(e - s for s, e in iv)
         edges = [w0] + [x for se in iv for x in se] + [w1]

@@ -36,7 +36,7 @@ def test_benchmark_json_keys_and_names():
         assert m["moves"] in e2e and set(m["workloads"]) <= cells
         assert (REPO / "bench/layers" / f"{m['name']}.py").is_file()
     for w in BM["workloads"]:
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert (REPO / "bench/traffic" / f"{w['traffic']}.json").is_file()
         assert (REPO / "bench/limits" / f"{w['name']}.json").is_file()
 
@@ -65,6 +65,40 @@ def test_config_triangle_counts():
     assert c["benchmark-share4"]["whole"]["triangles"] == 4 * 52_480
     assert c["gbr-share64"]["triangles"] == 51_520        # 2 rows short
     assert c["gbr-share64"]["whole"]["triangles"] == 3_302_400
+    assert c["benchmark-4chip"]["triangles"] == 209_920
+
+
+def test_four_chip_config_is_the_whole_benchmark_mesh():
+    from repro.launch.ocean_dryrun import OCEAN_CELLS
+    cell = OCEAN_CELLS["benchmark"]
+    conf = json.loads(
+        (REPO / "bench/configs/benchmark-4chip.json").read_text())
+    whole = {"nx": cell.nx, "ny": cell.ny, "lx": cell.lx, "ly": cell.ly,
+             "triangles": 2 * cell.nx * cell.ny}
+    assert conf["whole"] == whole
+    assert {k: conf[k] for k in whole} == whole
+    assert (conf["nl"], conf["m_2d"], conf["dt"], conf["depth"]) == (
+        cell.nl, cell.m_2d, cell.dt, cell.depth)
+    assert conf["reduced"] == []
+    # the one-chip share's physics, precision and guarantees
+    share = json.loads(
+        (REPO / "bench/configs/benchmark-share4.json").read_text())
+    for k in harness.PHYSICS + ("dtype", "guarantees", "jitter",
+                                "mesh_seed", "shallow_frac", "reef"):
+        assert conf[k] == share[k], k
+
+
+def test_at_most_half_the_cells_take_four_chips():
+    chips = [w["chips"] for w in BM["workloads"]]
+    assert chips.count(4) <= max(1, len(chips) // 2)
+    multi = {w["name"] for w in BM["workloads"] if w["chips"] > 1}
+    for name in multi:
+        assert (REPO / "bench/limits" / f"{name}.json").is_file(), name
+    # the halo's metrics read an exchange between chips: cells on one chip
+    # have none
+    for m in BM["per_layer"]:
+        if m["name"].startswith("halo."):
+            assert set(m["workloads"]) <= multi, m["name"]
 
 
 def test_work_hand_counts():

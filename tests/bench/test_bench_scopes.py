@@ -61,7 +61,7 @@ def test_every_burst_gather_is_named(step_scopes):
 # ------------------------------------------------------------ hand-made
 def _op(scope, ms):
     return Op(name="fusion.1", scope=scope, start_ns=0.0, dur_ns=ms * 1e6,
-              device=0, kernel=False)
+              device=0, kernel=False, end_ns=ms * 1e6)
 
 
 def _read(metric, ops, steps=2):

@@ -8,6 +8,7 @@ of the ``XLA Modules`` line), the spans clipped to the step, each event's
 metadata named by its HLO instruction alone (``tpu_custom_call`` where it is
 one), written with the subset of the XSpace schema the slice needs and the
 part of ``scopes.json`` it uses."""
+import dataclasses
 import gzip
 import json
 import math
@@ -79,7 +80,8 @@ def test_readers_on_the_slice(trace):
     ctx = _ctx(trace)
     got = {m["name"]: harness.layer_reader(m["name"])(trace, ctx)
            for m in json.loads(
-               (REPO / "BENCHMARK.json").read_text())["per_layer"]}
+               (REPO / "BENCHMARK.json").read_text())["per_layer"]
+           if "gbr-share64.wave-front" in m["workloads"]}
     assert all(v is not None for v in got.values()), got
     assert 0 <= got["device.idle_share"] < 100
     assert 0 < got["kernels_roofline"] <= 100
@@ -110,3 +112,16 @@ def test_union_and_names():
            '  ROOT %tuple.3 = (f32[3]{0}) tuple(%fusion.12)\n')
     assert trace_reduce.hlo_scopes(hlo) == {
         "fusion.12": "jit(f)/imex.stage1/stage.w_solve/add"}
+
+
+def test_step_mfu_counts_one_chips_share(trace):
+    """On one chip the reading is the whole step's least time over the busy
+    time per step, as before the reader knew chips; on four, where the busy
+    time is one chip's, it counts a quarter of the whole mesh's work."""
+    ctx = _ctx(trace)
+    read = harness.layer_reader("step_mfu")
+    one = read(trace, ctx)
+    lt, _ = work.least_seconds(*work.step(ctx.conf), ctx.peaks())
+    assert one == 100.0 * lt / (trace.busy_s / ctx.steps)
+    four = dataclasses.replace(ctx, chips=4, notes=[])
+    assert read(trace, four) == pytest.approx(one / 4, rel=1e-15)

@@ -15,9 +15,11 @@ TINY = {"name": "tiny", "nx": 8, "ny": 6, "lx": 8000.0, "ly": 6000.0,
         "triangles": 96, "nl": 4}
 
 
-def add_cell(root: Path, conf: dict, traffic: str, limits: dict) -> str:
-    """Add a configuration file and a cell of it to the benchmark copy at
-    ``root``; every per-layer metric lists the new cell."""
+def add_cell(root: Path, conf: dict, traffic: str, limits: dict,
+             chips: int = 1) -> str:
+    """Add a configuration file and a cell of it on ``chips`` chips to the
+    benchmark copy at ``root``; every per-layer metric lists the new
+    cell."""
     bm = json.loads((root / "BENCHMARK.json").read_text())
     (root / "bench/configs" / f"{conf['name']}.json").write_text(
         json.dumps(conf))
@@ -26,7 +28,8 @@ def add_cell(root: Path, conf: dict, traffic: str, limits: dict) -> str:
                           "reduced": [], "why": "test"})
     name = f"{conf['name']}.{traffic}"
     bm["workloads"].append({"name": name, "config": conf["name"],
-                            "traffic": traffic, "chips": 1, "why": "test"})
+                            "traffic": traffic, "chips": chips,
+                            "why": "test"})
     for m in bm["per_layer"]:
         m.setdefault("workloads", []).append(name)
     (root / "BENCHMARK.json").write_text(json.dumps(bm))
@@ -35,12 +38,13 @@ def add_cell(root: Path, conf: dict, traffic: str, limits: dict) -> str:
 
 
 def rehearse(root: Path, seed: int = 2 ** 33 + 7, seconds: float = 0.5,
-             trace: bool = False, break_step=None) -> dict:
+             trace: bool = False, break_step=None,
+             workload: str = TINY_CELL) -> dict:
     """One harness run of the tiny cell on the CPU, float32 as on the chip."""
     import jax
     from bench import harness
     with jax.enable_x64(False):
         return harness.run(harness.Options(
-            workload=TINY_CELL, seed=seed, seconds=seconds, trace=trace,
+            workload=workload, seed=seed, seconds=seconds, trace=trace,
             t_start=time.perf_counter(), require_tpu=False, repo=root,
             run_dir=root / "runs", break_step=break_step))
