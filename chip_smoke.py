@@ -20,7 +20,8 @@ share of it,
 same element size.  The v5e compile of that share's kernel-path step takes
 6.33 GB of temporaries and 0.20 GB of arguments, within 80% of the 15.75 GB
 the chip gives a program; the unfused reference step takes 5.54 GB.  Over
-four chips the whole mesh takes 7.92 GB of temporaries per chip.
+four chips the whole mesh, with the kernels on under ``shard_map``, takes
+9.51 GB of temporaries per chip (a v5e:2x2 compile).
 
 One chip, in order:
   0. each of the four ocean kernels, compiled, against its jnp oracle in
@@ -389,7 +390,7 @@ def four_chips(n_chips: int, full, share):
         case = sim_campaign.build_case(cell=cell, dtype=jnp.float32)
         do = DistributedOcean(m, b, cfg, dmesh, ("x",))
         say(phase="distributed", cell=cell.name, triangles=m.nt,
-            devices=n_chips, nt_local=do.spec.n_loc, backend=do.cfg.backend,
+            devices=n_chips, nt_local=do.spec.n_loc, backend=do.backend,
             matmul_precision=do.cfg.matmul_precision)
         return case, do
 

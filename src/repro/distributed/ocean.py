@@ -5,10 +5,16 @@ Hilbert stripes, ghost-ring halo exchange via ppermute (halo.py).  All
 per-partition data is stacked along a leading axis and sharded over the
 flattened device mesh axes, so the same SPMD program runs on 4 test devices
 or a 512-chip double pod.
+
+Every partition is padded to one local size ``n_loc`` (partition.py), so the
+shard-local step has static shapes and runs the same kernel backend as one
+device: ``cfg.backend`` ("auto" by default: compiled Pallas kernels on a TPU,
+interpreted on the CPU) reaches the column solves and the lateral flux
+inside ``shard_map``, each kernel padding the ``n_loc`` columns to its
+128-lane cell width.
 """
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
 from typing import Optional, Sequence
 
@@ -20,24 +26,19 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..core import geometry, mesh2d, stepper
 from ..core.dg2d import State2D
 from ..core.extrusion import VGrid
+from ..kernels import dispatch
 from ..obs import metrics as _metrics
 from . import halo, partition
 
 
 class DistributedOcean:
-    """Builds partition data and the sharded step function."""
+    """Builds partition data and the sharded step function.  ``cfg.backend``
+    is the one-device stepper's: any backend runs under ``shard_map``."""
 
     def __init__(self, mesh: mesh2d.Mesh2D, b_nodal: np.ndarray,
                  cfg: stepper.OceanConfig, device_mesh: jax.sharding.Mesh,
                  axes: Sequence[str], halo_depth: Optional[int] = None,
                  dtype=jnp.float32):
-        # the shard-local solves run the jnp reference until the kernels are
-        # wired under shard_map; "auto" means "ref" here
-        if cfg.backend not in ("auto", "ref"):
-            raise ValueError(
-                f"DistributedOcean runs backend 'ref' only, got "
-                f"{cfg.backend!r}; pass backend='auto' or 'ref'")
-        cfg = dataclasses.replace(cfg, backend="ref")
         self.cfg = cfg
         self.device_mesh = device_mesh
         self.axes = tuple(axes)
@@ -71,6 +72,13 @@ class DistributedOcean:
         reg.gauge("distributed.nt_local").set(self.spec.n_loc)
         reg.gauge("distributed.halo_slots").set(
             sum(int(s.shape[-1]) for s in self.tables.send))
+        reg.gauge("distributed.backend", backend=self.backend).set(1)
+
+    @property
+    def backend(self) -> str:
+        """The kernel backend the shard-local step runs ("auto" resolved
+        on this platform)."""
+        return dispatch.resolve(self.cfg.backend).value
 
     def _place(self, tree):
         """Host arrays with a leading partition axis -> sharded arrays."""
@@ -95,14 +103,19 @@ class DistributedOcean:
             return jnp.asarray(partition.gather_field(spec, x))
         return jax.tree_util.tree_map(ga, st_stk)
 
-    def init_state(self) -> stepper.OceanState:
-        """Stacked initial state (already partitioned)."""
+    def _init_local(self) -> stepper.OceanState:
+        """One partition's initial state (partition 0's geometry), in the
+        partition data's dtype."""
         geom0 = jax.tree_util.tree_map(lambda x: x[0], self.geom_stk)
         vg = VGrid(b=self.b_stk[0], nl=self.cfg.nl)
-        st = stepper.init_state(geom0, vg)
+        return stepper.init_state(geom0, vg, dtype=self.b_stk.dtype)
+
+    def init_state(self) -> stepper.OceanState:
+        """Stacked initial state (already partitioned)."""
         return self._place(jax.tree_util.tree_map(
             lambda x: np.broadcast_to(np.asarray(x)[None],
-                                      (self.n_parts,) + x.shape), st))
+                                      (self.n_parts,) + x.shape),
+            self._init_local()))
 
     # -- the sharded step -------------------------------------------------------
     def make_step_args(self, forcing: Optional[stepper.Forcing3D] = None):
@@ -142,8 +155,10 @@ class DistributedOcean:
         return partial(fn, self.geom_stk, self.b_stk, self.tables)
 
     def abstract_args(self):
-        """ShapeDtypeStruct stand-ins for (geom, b, tables, state)."""
-        sds = lambda t: jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+        """ShapeDtypeStruct stand-ins for (geom, b, tables, state); the
+        state's shapes come from ``jax.eval_shape``, so nothing is built or
+        placed."""
+        sds = lambda t, lead=(): jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(lead + x.shape, x.dtype), t)
         return (sds(self.geom_stk), sds(self.b_stk), sds(self.tables),
-                sds(self.init_state()))
+                sds(jax.eval_shape(self._init_local), (self.n_parts,)))

@@ -9,6 +9,8 @@ Two configurations:
 Each lowers one full split-IMEX internal step (both stages, both external
 bursts, implicit solves, GLS) of the shard_map'd distributed stepper with
 ShapeDtypeStruct inputs for the (16,16) and (2,16,16) production meshes.
+The shard-local step runs the kernel backend ``OceanConfig.backend``
+resolves to, as one device does: the compiled Pallas kernels on a TPU.
 """
 from __future__ import annotations
 

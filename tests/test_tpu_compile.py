@@ -74,6 +74,44 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sharded_step_compiles_for_v5e_2x2(one_chip, topo, monkeypatch):
+    """The whole shard-mapped step on a described 2x2 host with the
+    compiled kernels inside ``shard_map``, at a local size that is not a
+    multiple of the 128-lane cell width; the column solve is the kernel,
+    with no HLO LU under its scope."""
+    import re
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from repro.core import mesh2d, stepper
+    from repro.distributed.ocean import DistributedOcean
+    # nothing can be placed on a described device: keep the partition data
+    # on the host, where only their shapes are read
+    monkeypatch.setattr(DistributedOcean, "_place", lambda self, t: t)
+    m = mesh2d.rect_mesh(16, 8, 4000.0, 2000.0, jitter=0.2, seed=4)
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    cfg = stepper.OceanConfig(nl=3, dt=24.0, m_2d=2, use_gls=False,
+                              eos_kind="linear", backend="pallas")
+    do = DistributedOcean(m, np.full((3, m.nt), 20.0, np.float32), cfg,
+                          mesh, ("x",))
+    assert do.spec.n_loc % 128
+    shd = NamedSharding(mesh, do.pspec)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=shd),
+        do.abstract_args())
+    with jax.enable_x64(False):
+        hlo = jax.jit(do.make_step_args()).lower(*args).compile().as_text()
+    assert "collective-permute" in hlo
+    solve = [ln for ln in hlo.splitlines()
+             if re.search(r'op_name="[^"]*/kops\.block_thomas\.pallas/', ln)]
+    assert any("tpu_custom_call" in ln for ln in solve)
+    assert not any(re.search(r'op_name="[^"]*/lu"', ln) for ln in solve)
+    kernels = {k for k in ("block_thomas", "solve_r", "solve_w",
+                           "lateral_flux")
+               if re.search(rf'tpu_custom_call.*op_name="[^"]*'
+                            rf'/kops\.{k}\.pallas/', hlo)}
+    assert kernels == {"block_thomas", "solve_r", "solve_w", "lateral_flux"}
+
+
 def test_chip_smoke_needs_tpu_and_cache_follows_env(monkeypatch, tmp_path):
     if jax.default_backend() == "tpu":
         pytest.skip("the device check is exercised where there is no TPU")
