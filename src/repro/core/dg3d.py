@@ -77,15 +77,12 @@ def edge_ext_nodal6(geom: G.Geom2D, f: jax.Array) -> jax.Array:
     neighbour's values at the nodes facing my edge nodes a/b, on the top and
     bottom faces.  The qp-level exterior states (`lat_interp_ext`) are a
     linear map of these (see `lat_ext_from_nodal`), so the fused pipeline
-    gathers once here instead of at 12-qp width."""
-    ft, fb = f[..., 0:3, :], f[..., 3:6, :]
-    with jax.named_scope("gather.neighbour"):
-        ta = ft[..., geom.ext_na, geom.ext_tri]
-        tb = ft[..., geom.ext_nb, geom.ext_tri]
-        ba = fb[..., geom.ext_na, geom.ext_tri]
-        bb = fb[..., geom.ext_nb, geom.ext_tri]
-    return jnp.stack([jnp.stack([ta, ba], axis=-2),
-                      jnp.stack([tb, bb], axis=-2)], axis=-3)
+    gathers once here instead of at 12-qp width.  The whole (..., 6, nt)
+    field is one record gather by ext_tri (`G.edge_ext_record`, top and
+    bottom faces as a leading axis), then an exact pick."""
+    fa, fb = G.edge_ext_record(geom, f.reshape(f.shape[:-2] + (2, 3)
+                                               + f.shape[-1:]))
+    return jnp.moveaxis(jnp.stack([fa, fb], axis=-4), -2, -4)
 
 
 def own_nodal6(f: jax.Array) -> jax.Array:
@@ -479,13 +476,12 @@ def horizontal_diffusion(geom: G.Geom2D, vge: VertGeom, nl: int,
 def _gather_ext_grad(geom: G.Geom2D, gradf: jax.Array) -> jax.Array:
     """Exterior iso-zeta gradient dotted with our outward normal, per edge.
 
-    gradf: (k, nl, 2qz, 2comp, nt) constant-per-triangle gradients.
+    gradf: (k, nl, 2qz, 2comp, nt) constant-per-triangle gradients, both
+    components fetched by one record gather (`G.neighbour_record`).
     Returns (k, nl, 2qz, 3edge, nt): n_ours . grad_ext.
     """
-    with jax.named_scope("gather.neighbour"):
-        ge_x = gradf[..., 0, :][..., geom.ext_tri]      # (k,nl,2qz,3,nt)
-        ge_y = gradf[..., 1, :][..., geom.ext_tri]
-    return ge_x * geom.edge_nx + ge_y * geom.edge_ny
+    ge = G.neighbour_record(geom, gradf)              # (k,nl,2qz,2,3,nt)
+    return ge[..., 0, :, :] * geom.edge_nx + ge[..., 1, :, :] * geom.edge_ny
 
 
 def sigma3_lateral(geom: G.Geom2D, N0: float = 5.0, o: int = 1,

@@ -10,8 +10,13 @@ is the long, contiguous, lane-friendly dimension; see DESIGN.md §2):
   3D scalar field      T     : (nl, 6, nt)        [layer, node, tri]
   3D vector field      u     : (2, nl, 6, nt)
   edge-quad values           : (3, 2, nt)         [edge, qp, tri]
-  neighbour record           : (..., 3, 3, nt)    [node, edge, tri]: f's
-                               nodal values on the triangle across each edge
+  neighbour record           : (K, 3, nt)         [record, edge, tri]:
+                               f.reshape(K, nt)[:, ext_tri], f's values on
+                               the triangle across each edge; K is the size
+                               of all f's axes but the last: 9 for the
+                               burst's (3 fields, 3 nodes), k*nl*6 for 3D
+                               fields (k, nl, 6, nt), k*nl*2qz*2 for their
+                               gradients (k, nl, 2qz, 2, nt)
 
 Quadrature (used uniformly for ALL terms so that discrete consistency —
 free-surface vs continuity, tracer constancy — holds exactly):
@@ -196,36 +201,36 @@ def edge_interp(f: jax.Array) -> jax.Array:
     return edge_blend(fa, fb)
 
 
-def edge_ext_nodal(geom: Geom2D, f: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Neighbour nodal values facing my edge nodes a and b: two (..., 3, nt).
+def neighbour_record(geom: Geom2D, f: jax.Array) -> jax.Array:
+    """f's values on the triangle across each edge: (..., nt) -> (..., 3 edge,
+    nt), one gather by ext_tri alone of f's flat (K, nt) record.
 
-    Two point gathers by (ext_na, ext_tri) and (ext_nb, ext_tri).  Only the
-    3D stages still use it, through `edge_interp_ext` (horizontal.py,
-    dg3d.py), where each index fetches a whole column of layers
-    (`dg3d.edge_ext_nodal6` gathers the same way on its own); the external
-    mode's burst uses `edge_ext_record`.
-    """
-    with jax.named_scope("gather.neighbour"):
-        fa = f[..., geom.ext_na, geom.ext_tri]
-        fb = f[..., geom.ext_nb, geom.ext_tri]
-    return fa, fb
-
-
-def edge_ext_record(geom: Geom2D, f: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """`edge_ext_nodal` from one gather: the same two (..., 3, nt), bit for bit.
-
-    Fetches the neighbour's whole nodal record by ext_tri alone, (..., 3 node,
-    3 edge, nt), then picks the node facing my node a (ext_na) and the one
-    facing b (ext_nb) by an exact 3-way select.  A TPU gather costs per
-    index, not per value, so one index walk over a stacked f (the burst's
-    eta, qx, qy) replaces two per field.  The leading axes and the node axis
-    are gathered as one flat axis: on a TPU v5e at 52,480 triangles,
+    A TPU gather costs per index, not per value, so the leading axes are
+    gathered as one flat axis: on a TPU v5e at 52,480 triangles,
     (9, nt)[:, ext_tri] takes 0.29 ms, (3, 3, nt)[..., :, ext_tri] 1.16 ms
-    and one point gather of a (3, nt) field 1.61 ms.
+    and one point gather of a (3, nt) field 1.61 ms.  The rank-2 operand
+    also fixes the layout: XLA reads each index as one contiguous record
+    whatever nt is, where a point gather of a 3D field gets a triangle-minor
+    (strided) operand at some sizes (nt = 51,842 on a v5e, PERF.md section 5).
     """
     with jax.named_scope("gather.neighbour"):
         rec = f.reshape(-1, f.shape[-1])[:, geom.ext_tri]
-        rec = rec.reshape(f.shape[:-1] + rec.shape[-2:])
+        return rec.reshape(f.shape[:-1] + rec.shape[-2:])
+
+
+def edge_ext_record(geom: Geom2D, f: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Neighbour nodal values facing my edge nodes a and b: two (..., 3, nt).
+
+    Fetches the neighbour's whole nodal record, (..., 3 node, 3 edge, nt),
+    then picks the node facing my node a (ext_na) and the one facing b
+    (ext_nb) by an exact 3-way select: the values of the point gathers
+    f[..., ext_na, ext_tri] and f[..., ext_nb, ext_tri], bit for bit, from
+    one gather for a whole stack of fields.  The burst's (eta, qx, qy) and
+    the 3D stages' fields (`edge_interp_ext`, `dg3d.edge_ext_nodal6`) take
+    their neighbour values here: the record's rank-2 operand keeps one
+    contiguous record an index at every nt (`neighbour_record`).
+    """
+    rec = neighbour_record(geom, f)
     with jax.named_scope("gather.edge_local"):
         n0, n1, n2 = rec[..., 0, :, :], rec[..., 1, :, :], rec[..., 2, :, :]
         fa = jnp.where(geom.ext_na == 0, n0,
@@ -237,7 +242,7 @@ def edge_ext_record(geom: Geom2D, f: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def edge_interp_ext(geom: Geom2D, f: jax.Array) -> jax.Array:
     """Exterior (neighbour) values at my edge Gauss points: (..., 3, 2, nt)."""
-    return edge_blend(*edge_ext_nodal(geom, f))
+    return edge_blend(*edge_ext_record(geom, f))
 
 
 # scatter tensor: _EDGE_SCATTER[e, q, n] = w_q * phi_n(s_q) on edge e

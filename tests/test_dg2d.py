@@ -13,6 +13,7 @@ import pytest
 
 from repro.core import dg2d, geometry, mesh2d
 from repro.core.dg2d import Forcing2D, State2D
+from neighbour_gathers import edge_ext_point
 
 G_ = geometry.G_GRAV
 
@@ -173,13 +174,12 @@ def test_open_boundary_tidal_inflow():
 # ---------------------------------------------------------------------------
 def _edge_states_per_field(geom, st, forcing):
     """The per-field formula the record gather replaced: two point gathers
-    per field (`edge_interp_ext`), then the same ghost states."""
+    per field, then the same ghost states."""
     ei = geometry.edge_interp(st.eta)
     qxi = geometry.edge_interp(st.qx)
     qyi = geometry.edge_interp(st.qy)
-    ee = geometry.edge_interp_ext(geom, st.eta)
-    qxe = geometry.edge_interp_ext(geom, st.qx)
-    qye = geometry.edge_interp_ext(geom, st.qy)
+    ee, qxe, qye = (geometry.edge_blend(*edge_ext_point(geom, f))
+                    for f in (st.eta, st.qx, st.qy))
     nx = geom.edge_nx[:, None, :]
     ny = geom.edge_ny[:, None, :]
     wall = geom.wall[:, None, :]
@@ -232,12 +232,12 @@ def test_edge_states_match_per_field_gathers(dtype, open_bc):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
                          ids=["f32", "f64"])
 def test_edge_ext_record_matches_nodal(dtype, stacked):
-    """`edge_ext_record` returns `edge_ext_nodal`'s two arrays bit for bit,
+    """`edge_ext_record` returns the two point gathers' arrays bit for bit,
     for one (3, nt) field and for a stacked (3, 3, nt) one."""
     _, geom, st, _ = _channel(dtype, seed=9)
     f = jnp.stack([st.eta, st.qx, st.qy]) if stacked else st.qx
     got = geometry.edge_ext_record(geom, f)
-    want = geometry.edge_ext_nodal(geom, f)
+    want = edge_ext_point(geom, f)
     for g, w in zip(got, want):
         assert g.shape == f.shape
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -112,6 +112,43 @@ def test_sharded_step_compiles_for_v5e_2x2(one_chip, topo, monkeypatch):
     assert kernels == {"block_thomas", "solve_r", "solve_w", "lateral_flux"}
 
 
+def test_neighbour_gathers_read_records_for_v5e(one_chip):
+    """The one-chip step at 51,842 triangles (161 x 161 squares, the size of
+    gbr-4chip's n_loc; GBR physics, nl=20, the compiled kernels): every
+    neighbour gather reads a (K, nt) operand laid out triangle-major,
+    ``{0,1}``, one contiguous record an index.  The point gathers of 3D
+    fields that the records replaced got a triangle-minor operand at this
+    size (``f32[2,20,3,51842]{3,0,2,1}``, 40 strided values an index), and a
+    slab one at 51,520."""
+    import dataclasses
+    from repro.core import stepper
+    from repro.core.extrusion import VGrid
+    from repro.launch import sim_campaign
+    from repro.launch.ocean_dryrun import OceanCell, cell_setup
+    from neighbour_gathers import hlo_neighbour_gathers, is_record_gather
+    cell = OceanCell("gbr-n_loc", 161, 161, 250e3, 250e3, 20, 20, 45.0,
+                     120.0, reef=True)
+    with jax.enable_x64(False):
+        _, geom, b, cfg = cell_setup(cell, jnp.float32)
+        cfg = dataclasses.replace(cfg, backend="pallas")
+        vg = VGrid(b=jnp.asarray(b), nl=cfg.nl)
+        st = stepper.init_state(geom, vg, dtype=jnp.float32)
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (geom, vg, st))
+        hlo = sim_campaign.step_program.lower(
+            args[0], args[1], cfg, args[2]).compile().as_text()
+    assert geom.nt == 51_842
+    gathers = hlo_neighbour_gathers(hlo)
+    assert len(gathers) == 40, len(gathers)
+    assert all(is_record_gather(g) for g in gathers)
+    records = [g for g in gathers if len(g["operand"]) == 2]
+    assert len(records) == 39
+    assert all(g["layout"] == (0, 1) for g in records), [
+        g for g in records if g["layout"] != (0, 1)]
+
+
 def test_chip_smoke_needs_tpu_and_cache_follows_env(monkeypatch, tmp_path):
     if jax.default_backend() == "tpu":
         pytest.skip("the device check is exercised where there is no TPU")
